@@ -80,11 +80,10 @@ def build_case(b, kvh, g, d, ps, pages_resident, occupancy, fmt, bsz, rng):
     for name, src in [("ke", kq.elements), ("ks", kq.scales),
                       ("ve", vq.elements), ("vs", vq.scales)]:
         src = np.asarray(src)
-        pool = np.zeros((npg, ps, kvh, src.shape[-1]), src.dtype)
+        pool = np.zeros((npg, kvh, ps, src.shape[-1]), src.dtype)
         for i in range(b):
             for p in range(pages_resident):
-                pool[table[i, p]] = src[i, :, p * ps:(p + 1) * ps].transpose(
-                    1, 0, 2)
+                pool[table[i, p]] = src[i, :, p * ps:(p + 1) * ps]
         pools[name] = jnp.asarray(pool)
     q = jnp.asarray(rng.normal(size=(b, kvh, g, d)).astype(np.float32))
     lens = jnp.asarray(rng.integers(t_res - ps + 1, t_res + 1, size=b),
@@ -104,16 +103,13 @@ def einsum_decode(q, ke, ks, ve, vs, table, lens, *, fmt, bsz):
     from repro.core import QuantConfig
     from repro.nn import attention as A
 
-    npg, ps = ke.shape[0], ke.shape[1]
+    npg, ps = ke.shape[0], ke.shape[2]
     b, pmax = table.shape
     d = q.shape[-1]
     idx = jnp.clip(table, 0, npg - 1)
 
-    def gather(leaf):
-        return leaf[idx].reshape(b, pmax * ps, *leaf.shape[2:])
-
-    view = {"k_elems": gather(ke), "k_scales": gather(ks),
-            "v_elems": gather(ve), "v_scales": gather(vs)}
+    view = A._pages_view({"k_elems": ke, "k_scales": ks,
+                          "v_elems": ve, "v_scales": vs}, idx)
     acfg = A.AttnConfig(d_model=0, num_heads=q.shape[1] * q.shape[2],
                         num_kv_heads=q.shape[1], head_dim=d)
     quant = QuantConfig(fmt=fmt, block_size=bsz, quantize_kv_cache=True)

@@ -131,10 +131,10 @@ def kernel_visit_audit(*, prompt_len, chunk, ps, kvh, g, d):
     kw = rng.normal(size=(1, pad, kvh, d)).astype(np.float32)
     vw = rng.normal(size=(1, pad, kvh, d)).astype(np.float32)
     qw = rng.normal(size=(1, kvh, pad, g, d)).astype(np.float32)
-    pools = [jnp.zeros((npg, ps, kvh, d), jnp.float8_e4m3fn),
-             jnp.zeros((npg, ps, kvh, d // 32), jnp.uint8),
-             jnp.zeros((npg, ps, kvh, d), jnp.float8_e4m3fn),
-             jnp.zeros((npg, ps, kvh, d // 32), jnp.uint8)]
+    pools = [jnp.zeros((npg, kvh, ps, d), jnp.float8_e4m3fn),
+             jnp.zeros((npg, kvh, ps, d // 32), jnp.uint8),
+             jnp.zeros((npg, kvh, ps, d), jnp.float8_e4m3fn),
+             jnp.zeros((npg, kvh, ps, d // 32), jnp.uint8)]
     table = np.full((1, pmax), -1, np.int32)
     need = -(-prompt_len // ps)
     table[0, :need] = rng.permutation(npg)[:need]
@@ -144,8 +144,8 @@ def kernel_visit_audit(*, prompt_len, chunk, ps, kvh, g, d):
         real = min(chunk, prompt_len - start)
         _, pools, vis = mx_attention_prefill_fused(
             jnp.asarray(qw[:, :, start:start + chunk]),
-            jnp.asarray(kw[:, start:start + chunk]),
-            jnp.asarray(vw[:, start:start + chunk]),
+            jnp.asarray(kw[:, start:start + chunk].swapaxes(1, 2)),
+            jnp.asarray(vw[:, start:start + chunk].swapaxes(1, 2)),
             *pools, table, jnp.asarray([start], jnp.int32),
             jnp.asarray([start + real], jnp.int32),
             fmt_name="fp8_e4m3", block_size=32, debug_visits=True)

@@ -164,13 +164,13 @@ def visits_audit(rng):
         "fp8_e4m3", bsz)
     el = np.asarray(qd.elements).reshape(kvh, npages, ps, -1)
     sc = np.asarray(qd.scales).reshape(kvh, npages, ps, -1)
-    ke = np.ascontiguousarray(el.transpose(1, 2, 0, 3))
-    ks = np.ascontiguousarray(sc.transpose(1, 2, 0, 3))
+    ke = np.ascontiguousarray(el.transpose(1, 0, 2, 3))
+    ks = np.ascontiguousarray(sc.transpose(1, 0, 2, 3))
     r = len(starts)
     _, _, visits = mx_attention_ragged_fused(
         jnp.asarray(rng.normal(size=(r, kvh, w, g, d)).astype(np.float32)),
-        jnp.asarray(rng.normal(size=(r, w, kvh, d)).astype(np.float32)),
-        jnp.asarray(rng.normal(size=(r, w, kvh, d)).astype(np.float32)),
+        jnp.asarray(rng.normal(size=(r, kvh, w, d)).astype(np.float32)),
+        jnp.asarray(rng.normal(size=(r, kvh, w, d)).astype(np.float32)),
         jnp.asarray(ke), jnp.asarray(ks),
         jnp.asarray(ke.copy()), jnp.asarray(ks.copy()),
         jnp.asarray(table), jnp.asarray(starts, jnp.int32),

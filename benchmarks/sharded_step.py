@@ -132,6 +132,9 @@ def run_child(smoke):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(src)
     env.pop("XLA_FLAGS", None)
+    # the child models a mesh on host devices; on a chip host it must
+    # never contend with its parent for the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD % dict(mesh=MESH, smoke=smoke)],
         env=env, capture_output=True, text=True, timeout=900)

@@ -105,7 +105,7 @@ def kernel_visit_audit(rng, b, kvh, g, d, ps, pmax, tq):
     kv = [quantize(jnp.asarray(
         rng.normal(size=(npg * ps, d)).astype(np.float32)), "fp8_e4m3", 32)
         for _ in range(2)]
-    pools = [x.reshape(npg, ps, 1, -1).repeat(kvh, axis=2)
+    pools = [x.reshape(npg, 1, ps, -1).repeat(kvh, axis=1)
              for t in kv for x in (np.asarray(t.elements), np.asarray(t.scales))]
     table = np.full((b, pmax), -1, np.int32)
     lens = rng.integers(tq, pmax * ps + 1, size=b).astype(np.int32)

@@ -142,7 +142,7 @@ def _mx_fsdp_quantize(w, fmt, block_size, tp_on):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.ctx import current_mesh, shard_map_compat
+    from repro.parallel.ctx import current_mesh
 
     mesh = current_mesh()
     fmt_i = F.get_format(fmt)
@@ -183,8 +183,8 @@ def _mx_fsdp_quantize(w, fmt, block_size, tp_on):
                                     tiled=True)
         return elems, scales
 
-    elems, scales = shard_map_compat(body, mesh=mesh, in_specs=(w_spec,),
-                                     out_specs=out_specs, check_vma=False)(w)
+    elems, scales = jax.shard_map(body, mesh=mesh, in_specs=(w_spec,),
+                                  out_specs=out_specs, check_vma=False)(w)
     return MXTensor(elements=elems, scales=scales, fmt_name=fmt_i.name,
                     block_size=block_size, axis=0, shape=w.shape)
 

@@ -13,7 +13,7 @@ Three entry points, two cache layouts:
     across the batch or per-sequence (continuous batching decodes requests
     at different positions in the same step).
   * **paged, two-pass** (`mx_attention_decode_paged`): the cache lives in a
-    global page pool (num_pages, page_size, KVH, D) and each sequence owns
+    global page pool (num_pages, KVH, page_size, D) and each sequence owns
     a list of pages (its page-table row). `gather_kv_pages` is a Pallas
     kernel whose BlockSpec index maps read the scalar-prefetched page
     table — the DMA engine walks the page list directly, and the gathered
@@ -51,9 +51,16 @@ Layouts:
   kpos     (T,) or (B, T)    i32 (absolute positions; -1 = empty slot)
   pos      scalar or (B,)    i32 (last valid position per sequence)
   out      (B, KVH, G, D)    f32
-Paged pools: (NP, PS, KVH, D[/2]) elems, (NP, PS, KVH, D//k) scales,
+Paged pools: (NP, KVH, PS, D[/2]) elems, (NP, KVH, PS, D//k) scales,
 page_table (B, P) i32 (entries < 0 = unallocated; rows are masked out via
-seq_lens so garbage pages never contribute).
+seq_lens so garbage pages never contribute). The pools are KV-head major
+so one (page, kv-head) tile is a contiguous (PS, width) slab: the TPU
+compiler tiles the last two dimensions of every block, and a KV-head axis
+blocked at 1 in second-to-last place is an illegal tiling.
+
+Every paged kernel also returns a page-visit counter, one int32 per
+(row, kv-head) cell in SMEM (scalar stores to VMEM do not lower), which
+the wrappers hand back only under ``debug_visits``.
 
 Element formats are threaded explicitly (``fmt_name``, as ``mx_matmul``
 does) — fp4 packs two nibbles per stored byte, so the storage dtype alone
@@ -70,8 +77,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 
-from .compat import CompilerParams
-from .mx_matmul import _decode_e8m0, _decode_tile
+from .mx_matmul import _decode_tile, _fold_scales, _unpack_fp4, _unpack_fp6
+from .mx_quantize import quantize_tile
 
 NEG_INF = -2.0e38
 
@@ -107,12 +114,7 @@ def _dequant_rows(elems, scales, fmt_name: str, block_size: int):
     two packed nibbles per byte, and any future byte-backed format would
     make dtype sniffing silently wrong.
     """
-    t = elems.shape[0]
-    vals = _decode_tile(elems, fmt_name)
-    d = vals.shape[-1]
-    nb = d // block_size
-    s = _decode_e8m0(scales)  # (T, nb)
-    return (vals.reshape(t, nb, block_size) * s[:, :, None]).reshape(t, d)
+    return _fold_scales(_decode_tile(elems, fmt_name), scales, block_size)
 
 
 # ---------------------------------------------------------------------------
@@ -159,10 +161,8 @@ def _decode_bytes_as(bytes_tile, fmt_name: str) -> jnp.ndarray:
     w = fmt.storage_len(d)
     prefix = bytes_tile[..., :w]
     if fmt.name == "fp4_e2m1":
-        from .mx_matmul import _unpack_fp4
         return _unpack_fp4(prefix)
     if fmt.bits == 6:
-        from .mx_matmul import _unpack_fp6
         return _unpack_fp6(prefix, fmt.name)
     return _decode_u8_codes(prefix, fmt.exp_bits, fmt.mantissa_bits)
 
@@ -179,15 +179,12 @@ def _dequant_rows_mixed(bytes_tile, scales, fmt_id, mixed_fmts,
     trace. The E8M0 scale fold is format-independent (scales are
     recomputed at repack time because emax differs per format).
     """
-    t, d = bytes_tile.shape
     out = None
     for name in mixed_fmts:
         vals = _decode_bytes_as(bytes_tile, name)
         sel = fmt_id == F.FORMAT_IDS[name]
         out = vals if out is None else jnp.where(sel, vals, out)
-    nb = d // block_size
-    s = _decode_e8m0(scales)  # (T, nb)
-    return (out.reshape(t, nb, block_size) * s[:, :, None]).reshape(t, d)
+    return _fold_scales(out, scales, block_size)
 
 
 def _mx_attn_kernel(q_ref, ke_ref, ks_ref, ve_ref, vs_ref, kpos_ref,
@@ -252,7 +249,7 @@ def mx_attention_decode(q, k_elems, k_scales, v_elems, v_scales, kpos, pos,
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(q, k_elems, k_scales, v_elems, v_scales, kpos, pos)
@@ -272,32 +269,32 @@ def _gather_pages_kernel(pt_ref, ke_ref, ks_ref, ve_ref, vs_ref,
     straight from pool page ``page_table[b, p]`` — the kernel never touches
     a wide value and never materializes an indirection on the compute units.
     """
-    oke_ref[0, 0] = ke_ref[0, :, 0, :]
-    oks_ref[0, 0] = ks_ref[0, :, 0, :]
-    ove_ref[0, 0] = ve_ref[0, :, 0, :]
-    ovs_ref[0, 0] = vs_ref[0, :, 0, :]
+    oke_ref[0, 0] = ke_ref[0, 0]
+    oks_ref[0, 0] = ks_ref[0, 0]
+    ove_ref[0, 0] = ve_ref[0, 0]
+    ovs_ref[0, 0] = vs_ref[0, 0]
 
 
 def gather_kv_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_table,
                     *, interpret: bool | None = None):
     """Gather per-sequence K/V pages into contiguous compact caches.
 
-    Pools: (NP, PS, KVH, ED) elems + (NP, PS, KVH, NB) scales.
+    Pools: (NP, KVH, PS, ED) elems + (NP, KVH, PS, NB) scales.
     page_table: (B, P) int32, entries < 0 = unallocated (clamped to page 0;
     callers mask those rows via seq_lens).
     Returns (k_elems, k_scales, v_elems, v_scales) shaped (B, KVH, P*PS, ·).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    npages, ps, kvh, ed = ke_pool.shape
+    npages, kvh, ps, ed = ke_pool.shape
     nb = ks_pool.shape[-1]
     b, pmax = page_table.shape
     t = pmax * ps
     table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, npages - 1)
 
     def pool_spec(width):
-        return pl.BlockSpec((1, ps, 1, width),
-                            lambda i, j, p, pt: (pt[i, p], 0, j, 0))
+        return pl.BlockSpec((1, 1, ps, width),
+                            lambda i, j, p, pt: (pt[i, p], j, 0, 0))
 
     def out_spec(width):
         return pl.BlockSpec((1, 1, ps, width),
@@ -358,35 +355,14 @@ def mx_attention_decode_paged(q, ke_pool, ks_pool, ve_pool, vs_pool,
 def _quantize_rows(x, fmt_name: str, block_size: int):
     """(T, D) f32 -> (elements (T, ED) storage, scales (T, D//k) uint8).
 
-    The exact math of ``core.quantize`` (f32 work dtype) inlined for the
-    kernel: block amax -> E8M0 shared exponent (exponent-field floor-log2,
-    no transcendentals and no lookup tables — Pallas rejects captured
-    constant arrays) -> RNE saturating element cast. Bit-identical to the
-    host cache-write path (``attention._quantize_kv_token``), which is
-    what lets the fused prefill kernel's in-kernel page writes substitute
-    for the host ``jnp.at[].set`` install without perturbing a single
-    cache byte. Shares the arithmetic encoders with ``mx_quantize``'s
-    kernel, the repo's other in-kernel quantizer.
+    Bit-identical to the host cache-write path
+    (``attention._quantize_kv_token``, i.e. ``core.quantize``), which is
+    what lets the fused kernels' in-kernel page writes substitute for the
+    host ``jnp.at[].set`` install without perturbing a single cache byte.
+    Shares ``mx_quantize``'s tile quantizer, the repo's other in-kernel
+    quantizer.
     """
-    from .mx_quantize import (_encode_fp4_codes, _encode_fp6_codes,
-                              _floor_log2, _pack_fp4, _pack_fp6)
-
-    fmt = F.get_format(fmt_name)
-    t, d = x.shape
-    nb = d // block_size
-    blocked = x.reshape(t, nb, block_size)
-    amax = jnp.max(jnp.abs(blocked), axis=-1)  # (t, nb)
-    e_unb = _floor_log2(amax) - fmt.emax + F.E8M0_BIAS
-    e_biased = jnp.clip(jnp.where(amax > 0, e_unb, 0), 0,
-                        254).astype(jnp.uint8)
-    scale = _decode_e8m0(e_biased)[..., None]
-    ratio = jnp.where(scale > 0, blocked / scale, 0.0)
-    ratio = jnp.clip(ratio, -fmt.max, fmt.max).reshape(t, d)
-    if fmt.name == "fp4_e2m1":
-        return _pack_fp4(_encode_fp4_codes(ratio)), e_biased
-    if fmt.bits == 6:
-        return _pack_fp6(_encode_fp6_codes(ratio, fmt)), e_biased
-    return F.snap_to_fp8_grid(ratio, fmt).astype(fmt.storage_dtype), e_biased
+    return quantize_tile(x, F.get_format(fmt_name), block_size)
 
 
 #: row-tile budget for one flash-update step, in f32 elements of the
@@ -433,6 +409,19 @@ def _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap):
             probs, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[sl] = m_new
+
+
+def _cell(i):
+    """Flat page-visit counter slot of grid cell (row ``i``, kv-head
+    ``program_id(1)``) in a ``(R, KVH, P)`` page walk. Read grid indices
+    at the kernel's top level: interpret mode does not substitute them
+    inside ``pl.when`` bodies."""
+    return i * pl.num_programs(1) + pl.program_id(1)
+
+
+#: out spec of the page-visit counter: one int32 per (row, kv-head) cell,
+#: whole-array in SMEM (a scalar store to a VMEM block does not lower)
+_VISITS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _first_window_page(qpos_min, window, page_size: int):
@@ -494,6 +483,7 @@ def _mx_attn_fused_kernel(*refs, page_size: int, fmt_name: str,
          o_ref, visits_ref, m_ref, l_ref, acc_ref) = refs
     i = pl.program_id(0)
     p = pl.program_id(2)
+    cell = _cell(i)
     last = pl.num_programs(2) - 1
 
     @pl.when(p == 0)
@@ -501,7 +491,7 @@ def _mx_attn_fused_kernel(*refs, page_size: int, fmt_name: str,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        visits_ref[0, 0, 0] = 0
+        visits_ref[cell] = 0
 
     seq_len = lens_ref[i]  # wrapper-clamped to >= num_q
     valid_pages = pl.cdiv(seq_len, page_size)
@@ -512,18 +502,18 @@ def _mx_attn_fused_kernel(*refs, page_size: int, fmt_name: str,
         # the skip predicate's audit trail: counts page bodies actually
         # executed, so tests/benchmarks can assert work == resident pages
         # inside the window
-        visits_ref[0, 0, 0] += 1
+        visits_ref[cell] += 1
         q = q_ref[0, 0].astype(jnp.float32)  # (num_q * G, D)
         if mixed_fmts is None:
-            k = _dequant_rows(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows(ke_ref[0, 0], ks_ref[0, 0],
                               fmt_name, block_size)  # (PS, D)
-            v = _dequant_rows(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows(ve_ref[0, 0], vs_ref[0, 0],
                               fmt_name, block_size)
         else:
             fid = fmts_ref[tbl_ref[i, p]]
-            k = _dequant_rows_mixed(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows_mixed(ke_ref[0, 0], ks_ref[0, 0],
                                     fid, mixed_fmts, block_size)
-            v = _dequant_rows_mixed(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows_mixed(ve_ref[0, 0], vs_ref[0, 0],
                                     fid, mixed_fmts, block_size)
         kpos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
@@ -566,7 +556,7 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
     and row ``i``'s output is exactly what a one-token decode at position
     ``seq_len - Tq + i`` would compute.
 
-    q: (B, KVH, Tq, G, D); pools (NP, PS, KVH, ED/NB); page_table (B, P)
+    q: (B, KVH, Tq, G, D); pools (NP, KVH, PS, ED/NB); page_table (B, P)
     i32 (entries < 0 = unallocated, clamped); seq_lens (B,) valid cache
     rows per sequence *including* the chunk's own tokens (inactive rows
     may pass 0, clamped to Tq so every query position stays valid —
@@ -576,7 +566,7 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
 
     ``debug_visits=True`` additionally returns a (B, KVH, 1) i32 count of
     page bodies actually executed per cell — the kernel always maintains
-    it (one scalar store per visited tile), and tests/benchmarks assert
+    it (one SMEM scalar store per visited tile), and tests/benchmarks assert
     it equals ``ceil(seq_lens / PS)`` exactly (minus, under a sliding
     window, the head pages wholly below the oldest query's window, which
     are skipped like tail pages — visits is then exactly the page count
@@ -602,7 +592,7 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
     mixed_fmts = tuple(mixed_fmts) if mixed else None
     b, kvh, tq, g, d = q.shape
     rows = tq * g
-    npages, ps = ke_pool.shape[0], ke_pool.shape[1]
+    npages, ps = ke_pool.shape[0], ke_pool.shape[2]
     ed = ke_pool.shape[-1]
     nb = ks_pool.shape[-1]
     pmax = page_table.shape[1]
@@ -620,8 +610,8 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
             # DMA entirely, so skipped pages cost no HBM traffic.
             valid = pl.cdiv(ln[i], ps)
             first = _first_window_page(ln[i] - tq, window, ps)
-            return (tbl[i, jnp.clip(p, first, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, ps, 1, width), imap)
+            return (tbl[i, jnp.clip(p, first, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, ps, width), imap)
 
     scalar_ops = [table, lens]
     if mixed:
@@ -637,7 +627,7 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
         out_specs=[
             pl.BlockSpec((1, 1, rows, d),
                          lambda i, j, p, *_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda i, j, p, *_: (i, j, 0)),
+            _VISITS_SPEC,
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),  # running max m
@@ -654,14 +644,14 @@ def mx_attention_verify_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, kvh, rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b * kvh,), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*scalar_ops, qr, ke_pool, ks_pool, ve_pool, vs_pool)
     out = out.reshape(b, kvh, tq, g, d)
-    return (out, visits) if debug_visits else out
+    return (out, visits.reshape(b, kvh, 1)) if debug_visits else out
 
 
 def mx_attention_decode_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
@@ -683,7 +673,7 @@ def mx_attention_decode_fused(q, ke_pool, ks_pool, ve_pool, vs_pool,
     skipped, so per-step work scales with resident tokens rather than
     the padded table.
 
-    q: (B, KVH, G, D); pools (NP, PS, KVH, ED/NB); page_table (B, P) i32
+    q: (B, KVH, G, D); pools (NP, KVH, PS, ED/NB); page_table (B, P) i32
     (entries < 0 = unallocated, clamped — rows past ``seq_lens`` never
     contribute); seq_lens (B,) valid cache rows per sequence (the query
     sits at seq_len - 1; inactive rows may pass 0, clamped to 1 so the
@@ -761,6 +751,7 @@ def _mx_attn_prefill_kernel(*refs, page_size: int, fmt_name: str,
          m_ref, l_ref, acc_ref) = refs
     i = pl.program_id(0)
     p = pl.program_id(2)
+    cell = _cell(i)
     last = pl.num_programs(2) - 1
 
     @pl.when(p == 0)
@@ -768,7 +759,7 @@ def _mx_attn_prefill_kernel(*refs, page_size: int, fmt_name: str,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        visits_ref[0, 0, 0] = 0
+        visits_ref[cell] = 0
 
     start = start_ref[i]  # chunk start row, page-aligned
     seq_len = lens_ref[i]  # resident rows incl. this chunk's real tokens
@@ -792,38 +783,38 @@ def _mx_attn_prefill_kernel(*refs, page_size: int, fmt_name: str,
 
     @pl.when((p >= first_page) & (p < c0))
     def _resident_page():
-        visits_ref[0, 0, 0] += 1
+        visits_ref[cell] += 1
         if mixed_fmts is None:
-            k = _dequant_rows(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows(ke_ref[0, 0], ks_ref[0, 0],
                               fmt_name, block_size)  # (PS, D)
-            v = _dequant_rows(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows(ve_ref[0, 0], vs_ref[0, 0],
                               fmt_name, block_size)
         else:
             fid = fmts_ref[tbl_ref[i, p]]
-            k = _dequant_rows_mixed(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows_mixed(ke_ref[0, 0], ks_ref[0, 0],
                                     fid, mixed_fmts, block_size)
-            v = _dequant_rows_mixed(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows_mixed(ve_ref[0, 0], vs_ref[0, 0],
                                     fid, mixed_fmts, block_size)
         _attend_tile(k, v)
 
     @pl.when((p >= c0) & (p < valid_pages))
     def _chunk_page():
-        visits_ref[0, 0, 0] += 1
-        kw = kc_ref[0, :, 0, :].astype(jnp.float32)  # (PS, D) wide
-        vw = vc_ref[0, :, 0, :].astype(jnp.float32)
+        visits_ref[cell] += 1
+        kw = kc_ref[0, 0].astype(jnp.float32)  # (PS, D) wide
+        vw = vc_ref[0, 0].astype(jnp.float32)
         kq_e, kq_s = _quantize_rows(kw, fmt_name, block_size)
         vq_e, vq_s = _quantize_rows(vw, fmt_name, block_size)
         if mixed_fmts is None:
-            oke_ref[0, :, 0, :] = kq_e
-            ove_ref[0, :, 0, :] = vq_e
+            oke_ref[0, 0] = kq_e
+            ove_ref[0, 0] = vq_e
         else:
             # hot-format fp8 bytes into the full-width uint8 rows
-            oke_ref[0, :, 0, :] = jax.lax.bitcast_convert_type(
+            oke_ref[0, 0] = jax.lax.bitcast_convert_type(
                 kq_e, jnp.uint8)
-            ove_ref[0, :, 0, :] = jax.lax.bitcast_convert_type(
+            ove_ref[0, 0] = jax.lax.bitcast_convert_type(
                 vq_e, jnp.uint8)
-        oks_ref[0, :, 0, :] = kq_s
-        ovs_ref[0, :, 0, :] = vq_s
+        oks_ref[0, 0] = kq_s
+        ovs_ref[0, 0] = vq_s
         # attend over the in-register dequantized snap — identical bytes
         # (and therefore identical f32 values) to what a later page read
         # would produce, without a round trip through HBM
@@ -858,9 +849,9 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
     Layouts::
 
       q          (B, KVH, C, G, D)  wide chunk queries (RoPE'd)
-      k_chunk    (B, C, KVH, D)     wide chunk keys (RoPE'd)
-      v_chunk    (B, C, KVH, D)     wide chunk values
-      pools      (NP, PS, KVH, ED/NB) as the decode/verify kernels
+      k_chunk    (B, KVH, C, D)     wide chunk keys (RoPE'd)
+      v_chunk    (B, KVH, C, D)     wide chunk values
+      pools      (NP, KVH, PS, ED/NB) as the decode/verify kernels
       page_table (B, P) i32         entries < 0 = unallocated (clamped)
       chunk_start (B,) i32          chunk's first absolute row; must be
                                     page-aligned (see alignment contract)
@@ -918,7 +909,7 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
         mixed_fmts = None
     b, kvh, c, g, d = q.shape
     rows = c * g
-    npages, ps = ke_pool.shape[0], ke_pool.shape[1]
+    npages, ps = ke_pool.shape[0], ke_pool.shape[2]
     ed = ke_pool.shape[-1]
     nb = ks_pool.shape[-1]
     pmax = page_table.shape[1]
@@ -946,15 +937,15 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
             c0 = st[i] // ps
             first = _first_window_page(st[i], window, ps)
             hi = jnp.maximum(c0 - 1, first)
-            return (tbl[i, jnp.clip(p, first, hi)], 0, j, 0)
-        return pl.BlockSpec((1, ps, 1, width), imap)
+            return (tbl[i, jnp.clip(p, first, hi)], j, 0, 0)
+        return pl.BlockSpec((1, 1, ps, width), imap)
 
     def chunk_in_spec():
         def imap(i, j, p, tbl, st, ln, *_fmts):
             # page p of the walk is chunk page p - c0; steps outside the
             # chunk range clamp to its ends (same-index revisit = no DMA)
-            return (i, jnp.clip(p - st[i] // ps, 0, cps - 1), j, 0)
-        return pl.BlockSpec((1, ps, 1, d), imap)
+            return (i, j, jnp.clip(p - st[i] // ps, 0, cps - 1), 0)
+        return pl.BlockSpec((1, 1, ps, d), imap)
 
     def pool_out_spec(width):
         def imap(i, j, p, tbl, st, ln, *_fmts):
@@ -963,8 +954,8 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
             # last written page park on it (flushed once at cell end)
             c0 = st[i] // ps
             valid = pl.cdiv(ln[i], ps)
-            return (tbl[i, jnp.clip(p, c0, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, ps, 1, width), imap)
+            return (tbl[i, jnp.clip(p, c0, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, ps, width), imap)
 
     scalar_ops = [table, start, lens]
     if mixed:
@@ -985,7 +976,7 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
                          lambda i, j, p, *_: (i, j, 0, 0)),
             pool_out_spec(ed), pool_out_spec(nb),
             pool_out_spec(ed), pool_out_spec(nb),
-            pl.BlockSpec((1, 1, 1), lambda i, j, p, *_: (i, j, 0)),
+            _VISITS_SPEC,
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),  # running max m
@@ -1006,19 +997,20 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
             jax.ShapeDtypeStruct(ks_pool.shape, ks_pool.dtype),
             jax.ShapeDtypeStruct(ve_pool.shape, ve_pool.dtype),
             jax.ShapeDtypeStruct(vs_pool.shape, vs_pool.dtype),
-            jax.ShapeDtypeStruct((b, kvh, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b * kvh,), jnp.int32),
         ],
         # pools update in place (operand indices count the scalar-prefetch
         # operands, then q, k_chunk, v_chunk, then the four pools)
         input_output_aliases={ns + 3 + k: 1 + k for k in range(4)},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*scalar_ops, qr, k_chunk, v_chunk,
       ke_pool, ks_pool, ve_pool, vs_pool)
     out = out.reshape(b, kvh, c, g, d)
     pools = (oke, oks, ove, ovs)
-    return (out, pools, visits) if debug_visits else (out, pools)
+    return ((out, pools, visits.reshape(b, kvh, 1)) if debug_visits
+            else (out, pools))
 
 
 # ---------------------------------------------------------------------------
@@ -1090,6 +1082,7 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
          m_ref, l_ref, acc_ref) = refs
     i = pl.program_id(0)
     p = pl.program_id(2)
+    cell = _cell(i)
     last = pl.num_programs(2) - 1
 
     @pl.when(p == 0)
@@ -1097,7 +1090,7 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        visits_ref[0, 0, 0] = 0
+        visits_ref[cell] = 0
 
     start = start_ref[i]  # first new-token row of this step
     seq_len = lens_ref[i]  # resident rows incl. this step's new tokens
@@ -1123,25 +1116,25 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
 
     @pl.when((p >= first_page) & (p < w0))
     def _resident_page():
-        visits_ref[0, 0, 0] += 1
+        visits_ref[cell] += 1
         if mixed_fmts is None:
-            k = _dequant_rows(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows(ke_ref[0, 0], ks_ref[0, 0],
                               fmt_name, block_size)  # (PS, D)
-            v = _dequant_rows(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows(ve_ref[0, 0], vs_ref[0, 0],
                               fmt_name, block_size)
         else:
             fid = fmts_ref[tbl_ref[i, p]]
-            k = _dequant_rows_mixed(ke_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+            k = _dequant_rows_mixed(ke_ref[0, 0], ks_ref[0, 0],
                                     fid, mixed_fmts, block_size)
-            v = _dequant_rows_mixed(ve_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+            v = _dequant_rows_mixed(ve_ref[0, 0], vs_ref[0, 0],
                                     fid, mixed_fmts, block_size)
         _attend_tile(k, v)
 
     @pl.when((p >= w0) & (p < valid_pages))
     def _write_page():
-        visits_ref[0, 0, 0] += 1
-        kw = kn_ref[0, :, 0, :].astype(jnp.float32)  # (W, D) wide new rows
-        vw = vn_ref[0, :, 0, :].astype(jnp.float32)
+        visits_ref[cell] += 1
+        kw = kn_ref[0, 0].astype(jnp.float32)  # (W, D) wide new rows
+        vw = vn_ref[0, 0].astype(jnp.float32)
         # scatter new row t onto page row j where start + t == p*PS + j:
         # a one-hot f32 matmul (products are 1.0*x or 0.0*x — exact), so
         # page rows outside [start, seq_len) gather exact zeros that the
@@ -1168,14 +1161,14 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
         # freshly quantized codes, the rest keep their stored bytes —
         # then write the whole tile back through the aliased output
         in_w = (kpos_rows >= start) & (kpos_rows < seq_len)  # (PS, 1)
-        k_codes = jnp.where(in_w, kq_e, ke_ref[0, :, 0, :])
-        v_codes = jnp.where(in_w, vq_e, ve_ref[0, :, 0, :])
-        k_scales = jnp.where(in_w, kq_s, ks_ref[0, :, 0, :])
-        v_scales = jnp.where(in_w, vq_s, vs_ref[0, :, 0, :])
-        oke_ref[0, :, 0, :] = k_codes
-        ove_ref[0, :, 0, :] = v_codes
-        oks_ref[0, :, 0, :] = k_scales
-        ovs_ref[0, :, 0, :] = v_scales
+        k_codes = jnp.where(in_w, kq_e, ke_ref[0, 0])
+        v_codes = jnp.where(in_w, vq_e, ve_ref[0, 0])
+        k_scales = jnp.where(in_w, kq_s, ks_ref[0, 0])
+        v_scales = jnp.where(in_w, vq_s, vs_ref[0, 0])
+        oke_ref[0, 0] = k_codes
+        ove_ref[0, 0] = v_codes
+        oks_ref[0, 0] = k_scales
+        ovs_ref[0, 0] = v_scales
         # attend over the merged tile — identical bytes (and therefore
         # identical f32 values) to what the split path's separate host
         # install + page re-read would produce
@@ -1221,9 +1214,9 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
       q          (R, KVH, W, G, D)  wide step queries (RoPE'd); W is the
                                     static row width = max over modes of
                                     the per-row new-token count
-      k_new      (R, W, KVH, D)     wide new keys (RoPE'd)
-      v_new      (R, W, KVH, D)     wide new values
-      pools      (NP, PS, KVH, ED/NB) as the decode/verify kernels
+      k_new      (R, KVH, W, D)     wide new keys (RoPE'd)
+      v_new      (R, KVH, W, D)     wide new values
+      pools      (NP, KVH, PS, ED/NB) as the decode/verify kernels
       page_table (R, P) i32         entries < 0 map to pool page NP - 1
       row_start  (R,) i32           first absolute row this step writes
       seq_lens   (R,) i32           row_start + n_new (n_new in [1, W])
@@ -1266,7 +1259,7 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
         mixed_fmts = None
     r, kvh, w, g, d = q.shape
     rows = w * g
-    npages, ps = ke_pool.shape[0], ke_pool.shape[1]
+    npages, ps = ke_pool.shape[0], ke_pool.shape[2]
     ed = ke_pool.shape[-1]
     nb = ks_pool.shape[-1]
     pmax = page_table.shape[1]
@@ -1287,14 +1280,14 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
             # clamp into that range so their DMA is elided
             valid = pl.cdiv(ln[i], ps)
             first = _first_window_page(st[i], window, ps)
-            return (tbl[i, jnp.clip(p, first, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, ps, 1, width_), imap)
+            return (tbl[i, jnp.clip(p, first, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, ps, width_), imap)
 
     def new_in_spec():
         # the step's wide new rows: one (W, D) slab per (row, head),
         # constant across the page walk (fetched once per cell)
-        return pl.BlockSpec((1, w, 1, d),
-                            lambda i, j, p, *_: (i, 0, j, 0))
+        return pl.BlockSpec((1, 1, w, d),
+                            lambda i, j, p, *_: (i, j, 0, 0))
 
     def pool_out_spec(width_):
         def imap(i, j, p, tbl, st, ln, *_fmts):
@@ -1303,8 +1296,8 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
             # last written page park on it (flushed once at cell end)
             w0 = st[i] // ps
             valid = pl.cdiv(ln[i], ps)
-            return (tbl[i, jnp.clip(p, w0, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, ps, 1, width_), imap)
+            return (tbl[i, jnp.clip(p, w0, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, ps, width_), imap)
 
     scalar_ops = [table, start, lens]
     if mixed:
@@ -1325,7 +1318,7 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
                          lambda i, j, p, *_: (i, j, 0, 0)),
             pool_out_spec(ed), pool_out_spec(nb),
             pool_out_spec(ed), pool_out_spec(nb),
-            pl.BlockSpec((1, 1, 1), lambda i, j, p, *_: (i, j, 0)),
+            _VISITS_SPEC,
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),  # running max m
@@ -1346,16 +1339,17 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
             jax.ShapeDtypeStruct(ks_pool.shape, ks_pool.dtype),
             jax.ShapeDtypeStruct(ve_pool.shape, ve_pool.dtype),
             jax.ShapeDtypeStruct(vs_pool.shape, vs_pool.dtype),
-            jax.ShapeDtypeStruct((r, kvh, 1), jnp.int32),
+            jax.ShapeDtypeStruct((r * kvh,), jnp.int32),
         ],
         # pools update in place (operand indices count the scalar-prefetch
         # operands, then q, k_new, v_new, then the four pools)
         input_output_aliases={ns + 3 + k: 1 + k for k in range(4)},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*scalar_ops, qr, k_new, v_new,
       ke_pool, ks_pool, ve_pool, vs_pool)
     out = out.reshape(r, kvh, w, g, d)
     pools = (oke, oks, ove, ovs)
-    return (out, pools, visits) if debug_visits else (out, pools)
+    return ((out, pools, visits.reshape(r, kvh, 1)) if debug_visits
+            else (out, pools))

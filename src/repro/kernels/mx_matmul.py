@@ -36,13 +36,106 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 from repro.core import formats as F
 
 # ---------------------------------------------------------------------------
 # In-kernel decode helpers (pure jnp: lower on TPU and in interpret mode)
+#
+# Mosaic lowers no reshape that splits or merges the lane (last) axis and
+# no direct cast between f32 and uint8, so the helpers below never do
+# either: per-block work broadcasts or reduces along lanes, and lane
+# (de)interleaving of packed sub-byte codes is a one-hot matmul. Those
+# matmuls are exact in one bf16 pass: every output sums exactly one
+# nonzero product of a 0/1 entry with a byte, an fp4/fp6 value or a
+# power-of-two scale, all of which bf16 holds exactly, into an f32
+# accumulator. The one exception is the E8M0 code 0, 2^-127, which is
+# subnormal: a backend that flushes it reads that block as zeros, an
+# error below 1e-33 in the decoded values. The E8M0 NaN code (255), which
+# no encoder here emits, poisons its whole row, not only its block.
+#
+# Each one-hot works on one aligned 128-lane chunk of its input whenever
+# the input is a whole number of chunks, so its size (and the MXU work per
+# lane) stays fixed however wide the K tile is.
 # ---------------------------------------------------------------------------
+
+_LANES = 128
+
+
+def _lane_dot(x: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:
+    """``x (..., a) @ m (a, b)`` in f32, exact for one-hot ``m`` and
+    bf16-exact ``x``."""
+    return jax.lax.dot_general(
+        x.astype(jnp.float32).astype(jnp.bfloat16), m,
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _onehot(rows: int, cols: int, pred) -> jnp.ndarray:
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return pred(r, c).astype(jnp.bfloat16)
+
+
+def _chunk(n: int, width: int) -> int:
+    """``width`` if an ``n``-lane input is a whole number of such chunks,
+    else ``n`` (one chunk)."""
+    return width if n % width == 0 else n
+
+
+def _over_chunks(n: int, c: int, fn) -> jnp.ndarray:
+    """``fn(start)`` for each ``c``-lane chunk of ``n`` lanes, concatenated
+    along lanes."""
+    outs = [fn(s) for s in range(0, n, c)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
+
+
+def _interleave(parts) -> jnp.ndarray:
+    """k arrays (..., n) -> (..., k*n) f32 with out[..., k*i + j] = parts[j][..., i]."""
+    k, n = len(parts), parts[0].shape[-1]
+    c = _chunk(n, _LANES)
+    hots = [_onehot(c, k * c, lambda r, q, j=j: q == k * r + j)
+            for j in range(k)]
+
+    def chunk(s):
+        out = _lane_dot(parts[0][..., s:s + c], hots[0])
+        for p, hot in zip(parts[1:], hots[1:]):
+            out = out + _lane_dot(p[..., s:s + c], hot)
+        return out
+
+    return _over_chunks(n, c, chunk)
+
+
+def _deinterleave(x: jnp.ndarray, k: int):
+    """(..., k*n) -> k int32 arrays (..., n) with parts[j][..., i] = x[..., k*i + j]."""
+    kn = x.shape[-1]
+    c = _chunk(kn, k * _LANES)
+    xi = x.astype(jnp.int32)
+    parts = []
+    for j in range(k):
+        hot = _onehot(c, c // k, lambda r, q, j=j: r == k * q + j)
+        parts.append(_over_chunks(
+            kn, c, lambda s, hot=hot: _lane_dot(xi[..., s:s + c], hot)
+        ).astype(jnp.int32))
+    return parts
+
+
+def _broadcast_blocks(s: jnp.ndarray, block_size: int) -> jnp.ndarray:
+    """(..., nb) per-block values -> (..., nb * block_size), each value
+    repeated over its block's lanes."""
+    nb = s.shape[-1]
+    c = _chunk(nb, _LANES)
+    hot = _onehot(c, c * block_size, lambda r, q: q // block_size == r)
+    return _over_chunks(nb, c, lambda i: _lane_dot(s[..., i:i + c], hot))
+
+
+def _block_amax(x: jnp.ndarray, block_size: int) -> jnp.ndarray:
+    """(T, D) f32 -> (T, D // block_size) per-block max of ``|x|``."""
+    t, d = x.shape
+    blk = jax.lax.broadcasted_iota(jnp.int32, (t, d), 1) // block_size
+    a = jnp.abs(x)
+    return jnp.concatenate(
+        [jnp.max(jnp.where(blk == b, a, 0.0), axis=1, keepdims=True)
+         for b in range(d // block_size)], axis=1)
 
 
 def _decode_e8m0(e: jnp.ndarray) -> jnp.ndarray:
@@ -65,9 +158,9 @@ def _decode_fp4_codes(codes: jnp.ndarray) -> jnp.ndarray:
 
 def _unpack_fp4(packed: jnp.ndarray) -> jnp.ndarray:
     """(..., n) packed bytes -> (..., 2n) f32 values (low nibble first)."""
-    lo = _decode_fp4_codes(packed & 0xF)
-    hi = _decode_fp4_codes((packed >> 4) & 0xF)
-    return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], -1)
+    b = packed.astype(jnp.int32)
+    return _interleave([_decode_fp4_codes(b & 0xF),
+                        _decode_fp4_codes((b >> 4) & 0xF)])
 
 
 def _decode_fp6_codes(codes: jnp.ndarray, fmt_name: str) -> jnp.ndarray:
@@ -96,15 +189,12 @@ def _decode_fp6_codes(codes: jnp.ndarray, fmt_name: str) -> jnp.ndarray:
 
 def _unpack_fp6(packed: jnp.ndarray, fmt_name: str) -> jnp.ndarray:
     """(..., 3n) packed bytes -> (..., 4n) f32 values (low bits first)."""
-    b = packed.astype(jnp.int32).reshape(*packed.shape[:-1], -1, 3)
-    b0, b1, b2 = b[..., 0], b[..., 1], b[..., 2]
-    c0 = b0 & 0x3F
-    c1 = ((b0 >> 6) | (b1 << 2)) & 0x3F
-    c2 = ((b1 >> 4) | (b2 << 4)) & 0x3F
-    c3 = (b2 >> 2) & 0x3F
-    codes = jnp.stack([c0, c1, c2, c3], axis=-1)
-    vals = _decode_fp6_codes(codes, fmt_name)
-    return vals.reshape(*packed.shape[:-1], -1)
+    b0, b1, b2 = _deinterleave(packed, 3)
+    codes = [b0 & 0x3F,
+             ((b0 >> 6) | (b1 << 2)) & 0x3F,
+             ((b1 >> 4) | (b2 << 4)) & 0x3F,
+             (b2 >> 2) & 0x3F]
+    return _interleave([_decode_fp6_codes(c, fmt_name) for c in codes])
 
 
 def _decode_tile(tile: jnp.ndarray, fmt_name: str) -> jnp.ndarray:
@@ -118,10 +208,7 @@ def _decode_tile(tile: jnp.ndarray, fmt_name: str) -> jnp.ndarray:
 
 def _fold_scales(vals: jnp.ndarray, scales_e8m0: jnp.ndarray, block_size: int):
     """Fold per-block power-of-two scales into decoded element rows (exact)."""
-    r, bk = vals.shape
-    nb = bk // block_size
-    s = _decode_e8m0(scales_e8m0)  # (r, nb)
-    return (vals.reshape(r, nb, block_size) * s[:, :, None]).reshape(r, bk)
+    return vals * _broadcast_blocks(_decode_e8m0(scales_e8m0), block_size)
 
 
 # ---------------------------------------------------------------------------
@@ -171,7 +258,22 @@ def _mx_matmul_wo_kernel(
 
 
 def _elem_tile(bk: int, fmt_name: str) -> int:
-    return bk // 2 if fmt_name == "fp4_e2m1" else bk
+    return F.get_format(fmt_name).storage_len(bk)
+
+
+def _k_tile(k: int, block_size: int, pref: int) -> int:
+    """Tile of the blocked K axis whose scale block is a legal TPU tile.
+
+    The scale block is (rows, bk // block_size), and the TPU tiles a
+    block's last dimension in 128 lanes unless the block spans the whole
+    array: so bk // block_size is a multiple of 128, or bk is all of K.
+    Returns the largest such divisor of K up to ``pref``, else K.
+    """
+    step = 128 * block_size
+    for bk in range(pref - pref % step, 0, -step):
+        if k % bk == 0:
+            return bk
+    return k
 
 
 def mx_matmul_vv(
@@ -185,7 +287,7 @@ def mx_matmul_vv(
     acc_dtype=jnp.float32,
     bm: int = 128,
     bn: int = 128,
-    bk: int = 512,
+    bk: int = 4096,
     interpret: bool = False,
 ):
     """Tiled MX x MX matmul. Shapes per module docstring; returns (M, N)."""
@@ -193,8 +295,8 @@ def mx_matmul_vv(
     n = b_scales.shape[0]
     kb = a_scales.shape[1]
     k = kb * block_size
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    if m % bm or n % bn or k % bk or bk % block_size:
+    bm, bn, bk = min(bm, m), min(bn, n), _k_tile(k, block_size, bk)
+    if m % bm or n % bn or k % block_size:
         raise ValueError(f"tiling mismatch: {(m, n, k)} vs {(bm, bn, bk)}/{block_size}")
     ebk = _elem_tile(bk, fmt_name)
     nb = bk // block_size
@@ -213,7 +315,7 @@ def mx_matmul_vv(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_elems, a_scales, b_elems, b_scales)
 
@@ -228,14 +330,14 @@ def mx_matmul_wo(
     acc_dtype=jnp.float32,
     bm: int = 128,
     bn: int = 128,
-    bk: int = 512,
+    bk: int = 4096,
     interpret: bool = False,
 ):
     """Tiled wide-A x MX-B matmul (weight-only). Returns (M, N)."""
     m, k = a.shape
     n = b_scales.shape[0]
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    if m % bm or n % bn or k % bk or bk % block_size:
+    bm, bn, bk = min(bm, m), min(bn, n), _k_tile(k, block_size, bk)
+    if m % bm or n % bn or k % block_size:
         raise ValueError(f"tiling mismatch: {(m, n, k)} vs {(bm, bn, bk)}/{block_size}")
     ebk = _elem_tile(bk, fmt_name)
     nb = bk // block_size
@@ -253,7 +355,7 @@ def mx_matmul_wo(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b_elems, b_scales)
 
@@ -291,7 +393,7 @@ def mx_matmul_dgrad(
     out_dtype=jnp.float32,
     bm: int = 128,
     bn: int = 128,
-    bk: int = 512,
+    bk: int = 4096,
     interpret: bool = False,
 ):
     """dx (M, K) = dy (M, N) @ dequant(W)^T for W stored (N, K) MX-blocked
@@ -300,8 +402,8 @@ def mx_matmul_dgrad(
     m, n = dy.shape
     kb = b_scales.shape[1]
     k = kb * block_size
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    if m % bm or n % bn or k % bk or bk % block_size:
+    bm, bn, bk = min(bm, m), min(bn, n), _k_tile(k, block_size, bk)
+    if m % bm or n % bn or k % block_size:
         raise ValueError(f"tiling mismatch: {(m, n, k)} vs {(bm, bn, bk)}")
     ebk = _elem_tile(bk, fmt_name)
     nb = bk // block_size
@@ -318,7 +420,7 @@ def mx_matmul_dgrad(
         ],
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, nn: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dy, b_elems, b_scales)

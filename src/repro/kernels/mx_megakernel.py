@@ -55,7 +55,7 @@ Weight/pool layouts (``L`` = layer axis, indexed by grid dim 0)::
     norm_ffn    (L, DM)
     gate/up     (L, DM, DFF)        (gate absent for ffn_kind "gelu")
     down        (L, DFF, DM)
-    pools       (L, NP, PS, KVH, ED/NB)  stacked per-layer MX page pools
+    pools       (L, NP, KVH, PS, ED/NB)  stacked per-layer MX page pools
     page_table  (R, P) i32          shared by all layers; entries < 0 map
                                     to each layer's trash page (NP - 1)
     row_start   (R,) i32            first new-token row per ragged row
@@ -66,9 +66,9 @@ pools)`` — pool outputs alias the inputs. The final norm, logit-row
 gather, and LM head stay outside (they are row-gathered to ``num_logits``
 rows first; fusing the vocab matmul would multiply VMEM pressure for no
 dispatch win). ``debug_visits=True`` additionally returns the
-(L, R, KVH, 1) executed-page counter: each layer's page walk visits
-exactly the pages the per-layer ragged kernel reports, so summing over
-``L`` gives the whole step's page-visit audit.
+(L, R, KVH, 1) executed-page counter (an SMEM output): each layer's page
+walk visits exactly the pages the per-layer ragged kernel reports, so
+summing over ``L`` gives the whole step's page-visit audit.
 
 VMEM budget note: every per-layer weight block must fit in VMEM
 simultaneously with a pool tile, so very wide FFN blocks (8B-class
@@ -87,8 +87,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 
-from .compat import CompilerParams
-from .mx_attention import (NEG_INF, _check_fmt, _dequant_rows,
+from .mx_attention import (NEG_INF, _VISITS_SPEC, _check_fmt, _dequant_rows,
                            _dequant_rows_mixed, _first_window_page,
                            _flash_update, _quantize_rows,
                            MIXED_FMTS_DEFAULT)
@@ -134,6 +133,7 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
     last = pl.num_programs(3) - 1
     rows = width * group
     rs = pl.ds(i * width, width)
+    cell = (li * pl.num_programs(1) + i) * kvh + j  # visit counter slot
 
     @pl.when((li == 0) & (j == 0) & (p == 0))
     def _load_residual():
@@ -153,7 +153,7 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        visits_ref[0, 0, 0, 0] = 0
+        visits_ref[cell] = 0
         # this layer's pre-norm + this cell's KV-head slice of the fused
         # QKV projection (+ RoPE): the wq/wk/wv BlockSpecs already carved
         # out columns [j*G*D, (j+1)*G*D) / [j*D, (j+1)*D), and a
@@ -163,7 +163,7 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
         # same ops, bit-identical result; DM-wide, so the recompute is
         # noise next to the page walk).
         x = x_s[rs, :]
-        h = rmsnorm_apply({"scale": nm_ref[0]}, x, norm_eps)
+        h = rmsnorm_apply({"scale": nm_ref[0, 0]}, x, norm_eps)
         q = linear.apply({"w": wq_ref[0]}, h, quant, compute_dtype)
         k = linear.apply({"w": wk_ref[0]}, h, quant, compute_dtype)
         v = linear.apply({"w": wv_ref[0]}, h, quant, compute_dtype)
@@ -188,25 +188,25 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
 
     @pl.when((p >= first_page) & (p < w0))
     def _resident_page():
-        visits_ref[0, 0, 0, 0] += 1
+        visits_ref[cell] += 1
         if mixed_fmts is None:
-            k = _dequant_rows(ke_ref[0, 0, :, 0, :], ks_ref[0, 0, :, 0, :],
+            k = _dequant_rows(ke_ref[0, 0, 0], ks_ref[0, 0, 0],
                               fmt_name, block_size)  # (PS, D)
-            v = _dequant_rows(ve_ref[0, 0, :, 0, :], vs_ref[0, 0, :, 0, :],
+            v = _dequant_rows(ve_ref[0, 0, 0], vs_ref[0, 0, 0],
                               fmt_name, block_size)
         else:
             fid = fmts_ref[tbl_ref[i, p]]
-            k = _dequant_rows_mixed(ke_ref[0, 0, :, 0, :],
-                                    ks_ref[0, 0, :, 0, :],
+            k = _dequant_rows_mixed(ke_ref[0, 0, 0],
+                                    ks_ref[0, 0, 0],
                                     fid, mixed_fmts, block_size)
-            v = _dequant_rows_mixed(ve_ref[0, 0, :, 0, :],
-                                    vs_ref[0, 0, :, 0, :],
+            v = _dequant_rows_mixed(ve_ref[0, 0, 0],
+                                    vs_ref[0, 0, 0],
                                     fid, mixed_fmts, block_size)
         _attend_tile(k, v)
 
     @pl.when((p >= w0) & (p < valid_pages))
     def _write_page():
-        visits_ref[0, 0, 0, 0] += 1
+        visits_ref[cell] += 1
         kw = kn_s[...].astype(jnp.float32)  # (W, D) wide new rows
         vw = vn_s[...].astype(jnp.float32)
         # one-hot scatter + code-domain merge + aliased write: verbatim
@@ -231,14 +231,14 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
             kq_e = jax.lax.bitcast_convert_type(kq_e, jnp.uint8)
             vq_e = jax.lax.bitcast_convert_type(vq_e, jnp.uint8)
         in_w = (kpos_rows >= start) & (kpos_rows < seq_len)  # (PS, 1)
-        k_codes = jnp.where(in_w, kq_e, ke_ref[0, 0, :, 0, :])
-        v_codes = jnp.where(in_w, vq_e, ve_ref[0, 0, :, 0, :])
-        k_scales = jnp.where(in_w, kq_s, ks_ref[0, 0, :, 0, :])
-        v_scales = jnp.where(in_w, vq_s, vs_ref[0, 0, :, 0, :])
-        oke_ref[0, 0, :, 0, :] = k_codes
-        ove_ref[0, 0, :, 0, :] = v_codes
-        oks_ref[0, 0, :, 0, :] = k_scales
-        ovs_ref[0, 0, :, 0, :] = v_scales
+        k_codes = jnp.where(in_w, kq_e, ke_ref[0, 0, 0])
+        v_codes = jnp.where(in_w, vq_e, ve_ref[0, 0, 0])
+        k_scales = jnp.where(in_w, kq_s, ks_ref[0, 0, 0])
+        v_scales = jnp.where(in_w, vq_s, vs_ref[0, 0, 0])
+        oke_ref[0, 0, 0] = k_codes
+        ove_ref[0, 0, 0] = v_codes
+        oks_ref[0, 0, 0] = k_scales
+        ovs_ref[0, 0, 0] = v_scales
         if mixed_fmts is None:
             _attend_tile(
                 _dequant_rows(k_codes, k_scales, fmt_name, block_size),
@@ -272,7 +272,7 @@ def _mx_megakernel(*refs, page_size: int, fmt_name: str, block_size: int,
         x = x + h
         # the dense gated MLP tail (blocks._decode_tail with ffn "dense"):
         # same rmsnorm + ffn.apply calls on the loaded stacked blocks
-        h = rmsnorm_apply({"scale": nf_ref[0]}, x, norm_eps)
+        h = rmsnorm_apply({"scale": nf_ref[0, 0]}, x, norm_eps)
         fparams = {"up": {"w": up_ref[0]}, "down": {"w": down_ref[0]}}
         if has_gate:
             fparams["gate"] = {"w": gate_ref[0]}
@@ -344,7 +344,7 @@ def mx_megakernel_step(x0, norm_mixer, wq, wk, wv, wo, norm_ffn, gate, up,
             gate = _prequant(gate)
         quant = quant.replace(enabled=False)
     r, w, dm = x0.shape
-    layers, npages, ps = ke_pool.shape[0], ke_pool.shape[1], ke_pool.shape[2]
+    layers, npages, ps = ke_pool.shape[0], ke_pool.shape[1], ke_pool.shape[3]
     ed = ke_pool.shape[-1]
     nb = ks_pool.shape[-1]
     d = head_dim
@@ -364,15 +364,15 @@ def mx_megakernel_step(x0, norm_mixer, wq, wk, wv, wo, norm_ffn, gate, up,
         def imap(li, i, j, p, tbl, st, ln, *_fmts):
             valid = pl.cdiv(ln[i], ps)
             first = _first_window_page(st[i], window, ps)
-            return (li, tbl[i, jnp.clip(p, first, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, 1, ps, 1, width_), imap)
+            return (li, tbl[i, jnp.clip(p, first, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, 1, ps, width_), imap)
 
     def pool_out_spec(width_):
         def imap(li, i, j, p, tbl, st, ln, *_fmts):
             w0 = st[i] // ps
             valid = pl.cdiv(ln[i], ps)
-            return (li, tbl[i, jnp.clip(p, w0, valid - 1)], 0, j, 0)
-        return pl.BlockSpec((1, 1, ps, 1, width_), imap)
+            return (li, tbl[i, jnp.clip(p, w0, valid - 1)], j, 0, 0)
+        return pl.BlockSpec((1, 1, 1, ps, width_), imap)
 
     def wspec(shape, imap):
         return pl.BlockSpec(shape, imap)
@@ -380,14 +380,15 @@ def mx_megakernel_step(x0, norm_mixer, wq, wk, wv, wo, norm_ffn, gate, up,
     in_specs = [
         # x0: one (W, DM) slab per row, read once at layer 0
         wspec((1, w, dm), lambda li, i, j, p, *_: (i, 0, 0)),
-        wspec((1, dm), lambda li, i, j, p, *_: (li, 0)),       # norm_mixer
+        wspec((1, 1, dm), lambda li, i, j, p, *_: (li, 0, 0)),  # norm_mixer
         wspec((1, dm, g * d), lambda li, i, j, p, *_: (li, 0, j)),  # wq
         wspec((1, dm, d), lambda li, i, j, p, *_: (li, 0, j)),      # wk
         wspec((1, dm, d), lambda li, i, j, p, *_: (li, 0, j)),      # wv
         wspec((1, hd, dm), lambda li, i, j, p, *_: (li, 0, 0)),     # wo
-        wspec((1, dm), lambda li, i, j, p, *_: (li, 0)),       # norm_ffn
+        wspec((1, 1, dm), lambda li, i, j, p, *_: (li, 0, 0)),  # norm_ffn
     ]
-    weight_ops = [x0, norm_mixer, wq, wk, wv, wo, norm_ffn]
+    # norms ride as (L, 1, DM) so each layer's block spans both tiled dims
+    weight_ops = [x0, norm_mixer[:, None], wq, wk, wv, wo, norm_ffn[:, None]]
     if has_gate:
         dff = gate.shape[-1]
         in_specs.append(
@@ -416,7 +417,7 @@ def mx_megakernel_step(x0, norm_mixer, wq, wk, wv, wo, norm_ffn, gate, up,
             wspec((1, w, dm), lambda li, i, j, p, *_: (i, 0, 0)),
             pool_out_spec(ed), pool_out_spec(nb),
             pool_out_spec(ed), pool_out_spec(nb),
-            wspec((1, 1, 1, 1), lambda li, i, j, p, *_: (li, i, j, 0)),
+            _VISITS_SPEC,
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),   # running max m
@@ -445,15 +446,16 @@ def mx_megakernel_step(x0, norm_mixer, wq, wk, wv, wo, norm_ffn, gate, up,
             jax.ShapeDtypeStruct(ks_pool.shape, ks_pool.dtype),
             jax.ShapeDtypeStruct(ve_pool.shape, ve_pool.dtype),
             jax.ShapeDtypeStruct(vs_pool.shape, vs_pool.dtype),
-            jax.ShapeDtypeStruct((layers, r, kvh, 1), jnp.int32),
+            jax.ShapeDtypeStruct((layers * r * kvh,), jnp.int32),
         ],
         # stacked pools update in place (operand indices count the
         # scalar-prefetch operands, then x0 + weights, then the pools)
         input_output_aliases={ns + nin + k: 1 + k for k in range(4)},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
     )(*scalar_ops, *weight_ops, ke_pool, ks_pool, ve_pool, vs_pool)
     pools = (oke, oks, ove, ovs)
-    return ((x_out, pools, visits) if debug_visits else (x_out, pools))
+    return ((x_out, pools, visits.reshape(layers, r, kvh, 1))
+            if debug_visits else (x_out, pools))

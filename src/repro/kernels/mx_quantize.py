@@ -18,9 +18,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 from repro.core import formats as F
+
+from .mx_matmul import (_block_amax, _broadcast_blocks, _decode_e8m0,
+                        _deinterleave, _interleave)
 
 
 def _floor_log2(x: jnp.ndarray) -> jnp.ndarray:
@@ -30,7 +31,7 @@ def _floor_log2(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _encode_fp4_codes(v: jnp.ndarray) -> jnp.ndarray:
-    """Arithmetic RNE+saturate encode of f32 to E2M1 codes (no gather).
+    """Arithmetic RNE+saturate encode of f32 to E2M1 codes (int32, no gather).
 
     jnp.round implements round-half-to-even, so each regime below inherits
     correct tie behaviour; regime boundaries coincide with grid points.
@@ -42,18 +43,17 @@ def _encode_fp4_codes(v: jnp.ndarray) -> jnp.ndarray:
     r3 = jnp.round(mag * 0.5) * 2.0  # grid {4, 6}
     val = jnp.where(mag <= 1.75, r1, jnp.where(mag <= 3.5, r2, r3))
     code = jnp.where(val < 2.0, val * 2.0, jnp.where(val < 4.0, val + 2.0, val * 0.5 + 4.0))
-    code = code.astype(jnp.uint8)
-    return jnp.where(sign, code | jnp.uint8(0x8), code)
+    code = code.astype(jnp.int32)
+    return jnp.where(sign, code | 0x8, code)
 
 
 def _pack_fp4(codes: jnp.ndarray) -> jnp.ndarray:
-    lo = codes[..., 0::2]
-    hi = codes[..., 1::2]
+    lo, hi = _deinterleave(codes, 2)
     return (lo | (hi << 4)).astype(jnp.uint8)
 
 
 def _encode_fp6_codes(v: jnp.ndarray, fmt: F.ElementFormat) -> jnp.ndarray:
-    """Arithmetic RNE+saturate encode of f32 to FP6 codes (no gather).
+    """Arithmetic RNE+saturate encode of f32 to FP6 codes (int32, no gather).
 
     Same construction as :func:`repro.core.formats.fp6_encode` (grid snap
     via the exponent-field quantum, then exact field recovery) — pure
@@ -75,43 +75,48 @@ def _encode_fp6_codes(v: jnp.ndarray, fmt: F.ElementFormat) -> jnp.ndarray:
     frac = snapped - jnp.where(
         is_norm, jax.lax.bitcast_convert_type(p_bits, jnp.float32), 0.0)
     m = jnp.round(frac / quantum).astype(jnp.int32)
-    code = ((e_field << fmt.mantissa_bits) | m).astype(jnp.uint8)
-    return jnp.where(sign, code | jnp.uint8(0x20), code)
+    code = (e_field << fmt.mantissa_bits) | m
+    return jnp.where(sign, code | 0x20, code)
 
 
 def _pack_fp6(codes: jnp.ndarray) -> jnp.ndarray:
     """Pack quads of 6-bit codes into 3 bytes (low bits first)."""
-    c = codes.reshape(*codes.shape[:-1], -1, 4)
-    c0, c1, c2, c3 = c[..., 0], c[..., 1], c[..., 2], c[..., 3]
-    b0 = c0 | (c1 << 6)
-    b1 = (c1 >> 2) | (c2 << 4)
-    b2 = (c2 >> 4) | (c3 << 2)
-    packed = jnp.stack([b0, b1, b2], axis=-1)
-    return packed.reshape(*codes.shape[:-1], -1).astype(jnp.uint8)
+    c0, c1, c2, c3 = _deinterleave(codes, 4)
+    b0 = (c0 | (c1 << 6)) & 0xFF
+    b1 = ((c1 >> 2) | (c2 << 4)) & 0xFF
+    b2 = ((c2 >> 4) | (c3 << 2)) & 0xFF
+    return _interleave([b0, b1, b2]).astype(jnp.int32).astype(jnp.uint8)
 
 
-def _mx_quantize_kernel(x_ref, q_ref, e_ref, *, fmt: F.ElementFormat, block_size: int):
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    bm, bk = x.shape
-    nb = bk // block_size
-    blocked = x.reshape(bm, nb, block_size)
-    amax = jnp.max(jnp.abs(blocked), axis=-1)  # (bm, nb)
+def quantize_tile(x: jnp.ndarray, fmt: F.ElementFormat, block_size: int):
+    """(T, D) f32 -> (elements (T, ED) storage, E8M0 scales (T, D//k) uint8).
+
+    The exact math of ``core.quantize`` (f32 work dtype): block amax ->
+    E8M0 shared exponent (exponent-field floor-log2, no transcendentals
+    and no lookup tables — Pallas rejects captured constant arrays) ->
+    RNE saturating element cast. Dividing by a power of two is exact, so
+    multiplying by its reciprocal (the E8M0 code ``254 - e``) gives the
+    same bits as the host's division.
+    """
+    amax = _block_amax(x, block_size)  # (T, nb)
     e_unb = _floor_log2(amax) - fmt.emax + F.E8M0_BIAS
-    e = jnp.clip(jnp.where(amax > 0, e_unb, 0), 0, 254).astype(jnp.uint8)
-    e32 = e.astype(jnp.uint32)
-    scale_bits = jnp.where(e32 > 0, e32 << 23, jnp.uint32(0x00400000))
-    scale = jax.lax.bitcast_convert_type(scale_bits, jnp.float32)
-    ratio = jnp.where(scale[:, :, None] > 0, blocked / scale[:, :, None], 0.0)
-    ratio = jnp.clip(ratio, -fmt.max, fmt.max).reshape(bm, bk)
+    e = jnp.clip(jnp.where(amax > 0, e_unb, 0), 0, 254)
+    inv = _broadcast_blocks(_decode_e8m0(254 - e), block_size)
+    ratio = jnp.clip(x * inv, -fmt.max, fmt.max)
     if fmt.name == "fp4_e2m1":
-        q_ref[...] = _pack_fp4(_encode_fp4_codes(ratio))
+        elems = _pack_fp4(_encode_fp4_codes(ratio))
     elif fmt.bits == 6:
-        q_ref[...] = _pack_fp6(_encode_fp6_codes(ratio, fmt))
+        elems = _pack_fp6(_encode_fp6_codes(ratio, fmt))
     else:
         # exact RNE snap before the storage cast: XLA's direct fp8 cast
         # double-rounds via bf16 on some backends (see formats.py)
-        q_ref[...] = F.snap_to_fp8_grid(ratio, fmt).astype(fmt.storage_dtype)
-    e_ref[...] = e
+        elems = F.snap_to_fp8_grid(ratio, fmt).astype(fmt.storage_dtype)
+    return elems, e.astype(jnp.uint8)
+
+
+def _mx_quantize_kernel(x_ref, q_ref, e_ref, *, fmt: F.ElementFormat, block_size: int):
+    q_ref[...], e_ref[...] = quantize_tile(
+        x_ref[...].astype(jnp.float32), fmt, block_size)
 
 
 def mx_quantize(
@@ -146,6 +151,6 @@ def mx_quantize(
             jax.ShapeDtypeStruct((m, ek), fmt.storage_dtype),
             jax.ShapeDtypeStruct((m, k // block_size), jnp.uint8),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x)

@@ -47,7 +47,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 
-from .compat import CompilerParams
 from .mx_attention import (MIXED_FMTS_DEFAULT, _dequant_rows_mixed,
                            _quantize_rows)
 
@@ -64,9 +63,9 @@ def _repack_kernel(ids_ref, fmts_ref, cnt_ref, ke_ref, ks_ref, ve_ref,
         for e_in, s_in, e_out, s_out in (
                 (ke_ref, ks_ref, oke_ref, oks_ref),
                 (ve_ref, vs_ref, ove_ref, ovs_ref)):
-            rows = e_in[0, :, 0, :]  # (PS, D) uint8
+            rows = e_in[0, 0]  # (PS, D) uint8
             ps, d = rows.shape
-            wide = _dequant_rows_mixed(rows, s_in[0, :, 0, :], fid,
+            wide = _dequant_rows_mixed(rows, s_in[0, 0], fid,
                                        mixed_fmts, block_size)
             q_e, q_s = _quantize_rows(wide, dst_fmt_name, block_size)
             if dst.bits == 8:
@@ -75,8 +74,8 @@ def _repack_kernel(ids_ref, fmts_ref, cnt_ref, ke_ref, ks_ref, ve_ref,
                 w = dst.storage_len(d)
                 qb = jnp.concatenate(
                     [q_e, jnp.zeros((ps, d - w), jnp.uint8)], axis=-1)
-            e_out[0, :, 0, :] = qb
-            s_out[0, :, 0, :] = q_s
+            e_out[0, 0] = qb
+            s_out[0, 0] = q_s
 
 
 def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
@@ -84,8 +83,8 @@ def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
                     block_size: int = 32, interpret: bool | None = None):
     """Repack ``count`` pool pages to ``dst_fmt_name`` in place.
 
-    Pools are the tiered layout: (NP, PS, KVH, D) uint8 elements +
-    (NP, PS, KVH, D//k) uint8 E8M0 scales. ``page_ids``/``src_fmts`` are
+    Pools are the tiered layout: (NP, KVH, PS, D) uint8 elements +
+    (NP, KVH, PS, D//k) uint8 E8M0 scales. ``page_ids``/``src_fmts`` are
     fixed-size (N,) i32 arrays — the live prefix of length ``count``
     names the pages to repack and their *current* format ids
     (:data:`repro.core.formats.FORMAT_IDS`); padding entries repeat the
@@ -109,7 +108,7 @@ def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
     mixed_fmts = tuple(mixed_fmts)
     if dst_fmt_name not in F.FORMAT_IDS:
         raise ValueError(f"unknown target format {dst_fmt_name!r}")
-    npages, ps, kvh, d = ke_pool.shape
+    npages, kvh, ps, d = ke_pool.shape
     nb = ks_pool.shape[-1]
     nlist = page_ids.shape[0]
     ids = jnp.clip(jnp.asarray(page_ids, jnp.int32), 0, npages - 1)
@@ -117,8 +116,8 @@ def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
     cnt = jnp.asarray(count, jnp.int32).reshape(1)
 
     def spec(width):
-        return pl.BlockSpec((1, ps, 1, width),
-                            lambda n, j, ids, fmts, cnt: (ids[n], 0, j, 0))
+        return pl.BlockSpec((1, 1, ps, width),
+                            lambda n, j, ids, fmts, cnt: (ids[n], j, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -140,7 +139,7 @@ def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
         ],
         # pools update in place (operands: ids=0, fmts=1, cnt=2, pools 3-6)
         input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(ids, fmts, cnt, ke_pool, ks_pool, ve_pool, vs_pool)

@@ -76,7 +76,6 @@ def mx_matmul(
         asc = a.scales.reshape(m, -1)
         bm_ = _tile(m, _pick(bm, 128))
         bn_ = _tile(n, _pick(bn, 128))
-        bk_ = max(_tile(k, _pick(bk, 512)), block_size)
         out = _mm.mx_matmul_vv(
             ae,
             asc,
@@ -87,7 +86,7 @@ def mx_matmul(
             acc_dtype=acc_dtype,
             bm=bm_,
             bn=bn_,
-            bk=bk_,
+            bk=_pick(bk, 4096),
             interpret=interpret,
         )
     else:
@@ -98,7 +97,6 @@ def mx_matmul(
         a2 = a.reshape(m, k)
         bm_ = _tile(m, _pick(bm, 128))
         bn_ = _tile(n, _pick(bn, 128))
-        bk_ = max(_tile(k, _pick(bk, 512)), block_size)
         out = _mm.mx_matmul_wo(
             a2,
             b.elements,
@@ -108,7 +106,7 @@ def mx_matmul(
             acc_dtype=acc_dtype,
             bm=bm_,
             bn=bn_,
-            bk=bk_,
+            bk=_pick(bk, 4096),
             interpret=interpret,
         )
     out = out.reshape(*lead, n)
@@ -181,7 +179,6 @@ def _bwd(fmt, block_size, acc_dtype, res, dy):
         dy32.reshape(m, n), w_mx.elements, w_mx.scales,
         fmt_name=w_mx.fmt_name, block_size=w_mx.block_size,
         bm=_tile(m, 128), bn=_tile(n, 128),
-        bk=max(_tile(k, 512), w_mx.block_size),
         interpret=_default_interpret(),
     ).reshape(*lead, k).astype(x.dtype)
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)

@@ -13,26 +13,26 @@ import jax
 
 
 def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across versions: older JAX has no ``axis_types``."""
-    try:
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def require_devices(n: int, what: str):
+    """The first ``n`` devices, or an error naming what was found."""
+    devices = jax.devices()
+    if len(devices) < n:
+        raise ValueError(
+            f"{what} needs {n} devices, found {len(devices)} on platform "
+            f"{devices[0].platform!r} ({devices[0].device_kind})")
+    return devices[:n]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(devices)} — set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "any jax import (dryrun.py does this)")
-    return _make_mesh(shape, axes, devices[:n])
+    devices = require_devices(math.prod(shape), f"mesh {shape}")
+    return _make_mesh(shape, axes, devices)
 
 
 def make_host_mesh(model_parallel: int = 1):

@@ -29,11 +29,29 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import setup_compile_cache
 from repro.nn import model
 from repro.serve import (AsyncServeEngine, FixedSlotEngine, ServeConfig,
                          ServeEngine, ServeHTTPServer, TierPolicy)
 
 log = logging.getLogger("repro.serve")
+
+
+def serving_config(arch: str, *, reduced: bool = False, quant: str = "",
+                   quantize_kv: bool = False):
+    """The model config of ``arch`` as served: weight-only MX quantization
+    in ``quant``'s format (the arch's own block size) and, with
+    ``quantize_kv``, an MX-quantized paged KV cache."""
+    cfg = get_reduced(arch) if reduced else get_config(arch)
+    if quant:
+        from repro.core import MXFP4, MXFP8, WIDE
+
+        q = {"wide": WIDE, "mxfp8": MXFP8, "mxfp4": MXFP4}[quant]
+        cfg = cfg.replace(quant=q.replace(
+            block_size=cfg.quant.block_size,
+            quantize_acts=False,  # weight-only for serving
+            quantize_kv_cache=quantize_kv))
+    return cfg
 
 
 def build_engine(cfg, serve_cfg, params, kind: str):
@@ -201,9 +219,9 @@ def main(argv=None):
                          "projections split along the KV-head axis, wo "
                          "stays replicated behind the step's one "
                          "all-gather, tokens stay identical to "
-                         "single-device. Needs M devices (on CPU set "
-                         "XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=M), the ragged step mode, and "
+                         "single-device. Needs M devices (M chips, or "
+                         "on CPU XLA_FLAGS=--xla_force_host_platform_"
+                         "device_count=M), the ragged step mode, and "
                          "num_kv_heads divisible by M. 0 = unsharded")
     ap.add_argument("--spec-decode", action="store_true",
                     help="greedy speculative decoding: draft K tokens per "
@@ -232,16 +250,10 @@ def main(argv=None):
         args.quant = args.quant or "mxfp8"
     logging.basicConfig(level=logging.INFO)
 
-    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
-    if args.quant:
-        from repro.core import MXFP4, MXFP8, WIDE
-
-        q = {"wide": WIDE, "mxfp8": MXFP8, "mxfp4": MXFP4}[args.quant]
-        cfg = cfg.replace(quant=q.replace(
-            block_size=cfg.quant.block_size,
-            quantize_acts=False,  # weight-only for serving
-            quantize_kv_cache=args.quantize_kv))
-    params, _ = model.init(jax.random.PRNGKey(0), cfg)
+    setup_compile_cache()
+    cfg = serving_config(args.arch, reduced=args.reduced, quant=args.quant,
+                         quantize_kv=args.quantize_kv)
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
     max_seq = args.shared_prefix + args.prompt_len + args.new_tokens
     if args.spec_decode:
         # room for the worst-case verify window near the end of a request

@@ -156,25 +156,29 @@ def cache_len(cfg: AttnConfig, max_seq: int) -> int:
     return min(cfg.window, max_seq) if cfg.window else max_seq
 
 
-def _cache_arrays(lead, cfg: AttnConfig, quant: QuantConfig):
-    """Zero cache leaves with leading dims ``lead`` + (KVH, ·) storage.
+def _cache_arrays(lead, cfg: AttnConfig, quant: QuantConfig,
+                  paged: bool = False):
+    """Zero cache leaves: ``(B, T, KVH, ·)`` for ``lead = (B, T)``, or the
+    KV-head-major page pool ``(NP, KVH, PS, ·)`` for ``lead = (NP, PS)``
+    with ``paged``.
 
-    Single source of truth for the MX-vs-wide storage layout: the
+    Single source of truth for the MX-vs-wide storage leaves: the
     contiguous per-slot caches and the paged pools must agree exactly,
-    since prefill caches reshape 1:1 into pool pages.
+    since prefill caches reshape (and transpose) 1:1 into pool pages.
     """
     kvh, d = cfg.num_kv_heads, cfg.head_dim
+    dims = (lead[0], kvh, lead[1]) if paged else (*lead, kvh)
     if quant.quantize_kv_cache and quant.enabled:
         bs = min(quant.block_size, d)
         fmt = F.get_format(quant.fmt)
         ed = d // 2 if fmt.packed else d
-        zeros_e = jnp.zeros((*lead, kvh, ed), fmt.storage_dtype)
-        zeros_s = jnp.zeros((*lead, kvh, d // bs), jnp.uint8)
+        zeros_e = jnp.zeros((*dims, ed), fmt.storage_dtype)
+        zeros_s = jnp.zeros((*dims, d // bs), jnp.uint8)
         return {
             "k_elems": zeros_e, "k_scales": zeros_s,
             "v_elems": zeros_e, "v_scales": zeros_s,
         }
-    z = jnp.zeros((*lead, kvh, d), cfg.cache_dtype)
+    z = jnp.zeros((*dims, d), cfg.cache_dtype)
     return {"k": z, "v": z}
 
 
@@ -265,9 +269,19 @@ def gather_page_kv(pool, page_ids, cfg: AttnConfig, quant: QuantConfig,
     page-table order, so row ``t`` is absolute position ``t`` of the
     cached prefix.
     """
-    view = {key: leaf[page_ids].reshape(1, -1, *leaf.shape[2:])
-            for key, leaf in pool.items()}
-    return _read_cache(view, quant, cfg, dtype)
+    return _read_cache(_pages_view(pool, page_ids[None]), quant, cfg, dtype)
+
+
+def _pages_view(pool, page_rows):
+    """Gather (B, P) page rows of a (NP, KVH, PS, ·) pool into the
+    contiguous cache layout (B, P*PS, KVH, ·)."""
+    b, pmax = page_rows.shape
+
+    def gather(leaf):
+        pages = jnp.swapaxes(leaf[page_rows], 2, 3)  # (B, P, PS, KVH, ·)
+        return pages.reshape(b, pmax * leaf.shape[2], *pages.shape[3:])
+
+    return {key: gather(leaf) for key, leaf in pool.items()}
 
 
 def _project_decode_qkv(params, x, posv, cfg: AttnConfig,
@@ -320,8 +334,9 @@ def init_paged_pool(num_pages: int, page_size: int, cfg: AttnConfig,
                     quant: QuantConfig, tiered: bool = False):
     """Allocate a layer's global KV page pool (no per-sequence dimension).
 
-    Layout matches the paged Pallas kernels: (NP, PS, KVH, ·), with the
-    same storage leaves as the contiguous cache (``_cache_arrays``).
+    Layout matches the paged Pallas kernels: KV-head major
+    (NP, KVH, PS, ·), with the same storage leaves as the contiguous
+    cache (``_cache_arrays``).
     Ownership (which page belongs to which sequence at which position)
     lives in the host-side page table, not in the arrays.
 
@@ -334,7 +349,7 @@ def init_paged_pool(num_pages: int, page_size: int, cfg: AttnConfig,
     (fresh writes are always fp8; the repack ladder narrows them later).
     """
     if not tiered:
-        return _cache_arrays((num_pages, page_size), cfg, quant)
+        return _cache_arrays((num_pages, page_size), cfg, quant, paged=True)
     if not (quant.quantize_kv_cache and quant.enabled):
         raise ValueError("tiered KV pools require an MX-quantized cache")
     if F.get_format(quant.fmt).bits != 8:
@@ -343,8 +358,8 @@ def init_paged_pool(num_pages: int, page_size: int, cfg: AttnConfig,
             f"must be an fp8; got {quant.fmt!r}")
     kvh, d = cfg.num_kv_heads, cfg.head_dim
     bs = min(quant.block_size, d)
-    zeros_e = jnp.zeros((num_pages, page_size, kvh, d), jnp.uint8)
-    zeros_s = jnp.zeros((num_pages, page_size, kvh, d // bs), jnp.uint8)
+    zeros_e = jnp.zeros((num_pages, kvh, page_size, d), jnp.uint8)
+    zeros_s = jnp.zeros((num_pages, kvh, page_size, d // bs), jnp.uint8)
     return {"k_elems": zeros_e, "k_scales": zeros_s,
             "v_elems": zeros_e, "v_scales": zeros_s}
 
@@ -435,7 +450,7 @@ def apply_verify_paged(params, x, pool, page_rows, pos, cfg: AttnConfig,
     q, k, v = _project_decode_qkv(params, x, posv, cfg, quant, compute_dtype)
 
     lead = pool["k" if "k" in pool else "k_elems"]
-    npages, ps = lead.shape[0], lead.shape[1]
+    npages, ps = lead.shape[0], lead.shape[2]
     pmax = page_rows.shape[1]
     widx = posv // ps  # (B, Tq) page-table columns
     page = jnp.take_along_axis(page_rows, jnp.clip(widx, 0, pmax - 1),
@@ -448,11 +463,13 @@ def apply_verify_paged(params, x, pool, page_rows, pos, cfg: AttnConfig,
     page = jnp.where((page < 0) | (widx > pmax - 1), npages, page)
     slot = posv % ps
 
+    # pools are (NP, KVH, PS, ·): indexing [page, :, slot] with (B, Tq)
+    # page/slot arrays addresses (B, Tq, KVH, ·) rows, the K/V layout
     pool = dict(pool)
     if "k" in pool:
-        pool["k"] = pool["k"].at[page, slot].set(
+        pool["k"] = pool["k"].at[page, :, slot].set(
             k.astype(pool["k"].dtype), mode="drop")
-        pool["v"] = pool["v"].at[page, slot].set(
+        pool["v"] = pool["v"].at[page, :, slot].set(
             v.astype(pool["v"].dtype), mode="drop")
     else:
         kq, vq = _quantize_kv_token(k, v, cfg, quant)
@@ -461,13 +478,13 @@ def apply_verify_paged(params, x, pool, page_rows, pos, cfg: AttnConfig,
             # tiered pool: hot-format fp8 bytes into the uint8 byte rows
             k_el = jax.lax.bitcast_convert_type(k_el, jnp.uint8)
             v_el = jax.lax.bitcast_convert_type(v_el, jnp.uint8)
-        pool["k_elems"] = pool["k_elems"].at[page, slot].set(
+        pool["k_elems"] = pool["k_elems"].at[page, :, slot].set(
             k_el, mode="drop")
-        pool["k_scales"] = pool["k_scales"].at[page, slot].set(
+        pool["k_scales"] = pool["k_scales"].at[page, :, slot].set(
             kq.scales, mode="drop")
-        pool["v_elems"] = pool["v_elems"].at[page, slot].set(
+        pool["v_elems"] = pool["v_elems"].at[page, :, slot].set(
             v_el, mode="drop")
-        pool["v_scales"] = pool["v_scales"].at[page, slot].set(
+        pool["v_scales"] = pool["v_scales"].at[page, :, slot].set(
             vq.scales, mode="drop")
 
     if cfg.decode_kernel == "fused" and "k_elems" in pool:
@@ -485,12 +502,8 @@ def apply_verify_paged(params, x, pool, page_rows, pos, cfg: AttnConfig,
             b, tq, h, d).astype(compute_dtype)
     else:
         idx = jnp.clip(page_rows, 0, npages - 1)  # (B, P); garbage masked
-
-        def gather(leaf):
-            return leaf[idx].reshape(b, pmax * ps, *leaf.shape[2:])
-
-        view = {key: gather(leaf) for key, leaf in pool.items()}
-        kc, vc = _read_cache(view, quant, cfg, compute_dtype)
+        kc, vc = _read_cache(_pages_view(pool, idx), quant, cfg,
+                             compute_dtype)
         t = kc.shape[1]
         kpos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
         out = _attend(q, kc, vc, posv, kpos, cfg)
@@ -555,7 +568,8 @@ def apply_prefill_chunked(params, x, pool, page_rows, pos, num_valid,
                                       compute_dtype)
         qk = q.reshape(b, c, kvh, h // kvh, d).transpose(0, 2, 1, 3, 4)
         out, (ke, ks, ve, vs) = mx_attention_prefill_fused(
-            qk, k, v, pool["k_elems"], pool["k_scales"], pool["v_elems"],
+            qk, k.swapaxes(1, 2), v.swapaxes(1, 2), pool["k_elems"],
+            pool["k_scales"], pool["v_elems"],
             pool["v_scales"], page_rows, pos,
             pos + jnp.asarray(num_valid, jnp.int32),
             fmt_name=quant.fmt, block_size=min(quant.block_size, d),
@@ -645,7 +659,8 @@ def apply_ragged(params, x, pool, page_rows, row_start, seq_lens,
     g = q.shape[2] // kvh
     qk = q.reshape(r, w, kvh, g, d).transpose(0, 2, 1, 3, 4)
     out, (ke, ks, ve, vs) = mx_attention_ragged_fused(
-        qk, k, v, pool["k_elems"], pool["k_scales"], pool["v_elems"],
+        qk, k.swapaxes(1, 2), v.swapaxes(1, 2), pool["k_elems"],
+        pool["k_scales"], pool["v_elems"],
         pool["v_scales"], page_rows, row_start,
         jnp.asarray(seq_lens, jnp.int32),
         fmt_name=quant.fmt, block_size=min(quant.block_size, d),

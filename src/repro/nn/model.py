@@ -55,6 +55,16 @@ def init(key, cfg: ModelConfig):
     return params, axes
 
 
+def init_params(key, cfg: ModelConfig):
+    """Parameters only, made on the device by one jitted program and
+    stored as bf16 (serving holds bf16 masters, the dtype published
+    checkpoints ship in). Each leaf is drawn and cast inside one fusion,
+    so no f32 copy of the whole model ever exists: at 3.8B parameters
+    that copy alone would fill a 16 GB chip."""
+    return jax.jit(lambda k: jax.tree_util.tree_map(
+        lambda leaf: leaf.astype(jnp.bfloat16), init(k, cfg)[0]))(key)
+
+
 # ---------------------------------------------------------------------------
 # layer enumeration (shared by every per-layer walk and the megakernel)
 # ---------------------------------------------------------------------------
@@ -435,7 +445,7 @@ def init_megakernel_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
     axis (layer order = :func:`iter_layer_blocks`), wrapped as
     ``{"groups": (pool,)}`` so every ``serve.kv_cache`` structural walk —
     copy_page, extract/restore, ``pool_specs`` (KV heads stay at
-    ``ndim - 2``), repack — treats the layer axis exactly like the
+    ``ndim - 3``), repack — treats the layer axis exactly like the
     per-layer cache's group axis. For an attention-only config with
     ``pattern == (bd,)`` and ``num_groups == L`` this is bit-for-bit the
     same pytree layout as :func:`init_paged_cache`, which is what lets

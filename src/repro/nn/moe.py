@@ -180,10 +180,8 @@ def _mx_expert_weight(wt, quant: QuantConfig, contract_axis: int, dtype,
         return g.dequantize(dtype)
 
     out_dims = [d if i != dm_axis else None for i, d in enumerate(dims)]
-    from repro.parallel.ctx import shard_map_compat
-
-    return shard_map_compat(body, mesh=mesh, in_specs=(w_spec,),
-                            out_specs=P(*out_dims), check_vma=False)(wt)
+    return jax.shard_map(body, mesh=mesh, in_specs=(w_spec,),
+                         out_specs=P(*out_dims), check_vma=False)(wt)
 
 
 def _expert_ffn(w, h_in, quant: QuantConfig, kind: str, dtype):
@@ -314,9 +312,7 @@ def apply_sorted(params, x, cfg: MoEConfig, quant: QuantConfig,
         shared = params.pop("shared")
     else:
         shared = None
-    from repro.parallel.ctx import shard_map_compat
-
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         body, mesh=mesh, axis_names=set(data_axes),
         in_specs=(pspec, P(data_axes, None, None)),
         out_specs=(P(data_axes, None, None), P()),

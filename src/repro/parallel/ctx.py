@@ -70,32 +70,6 @@ def use_serve_tp(axis_name: Optional[str]):
         _SERVE_TP_AXIS.reset(token)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None,
-                     axis_names=None):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(check_vma=..., axis_names=...)``;
-    older JAX has ``jax.experimental.shard_map.shard_map(check_rep=...,
-    auto=...)`` where ``auto`` is the complement of the manual axis set.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def maybe_constrain(x, *entries):
     """Apply a logical sharding constraint if a mesh is active."""
     mesh = current_mesh()

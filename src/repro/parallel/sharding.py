@@ -50,18 +50,11 @@ PARAM_RULES = {
 
 
 def make_abstract_mesh(shape, axis_names):
-    """Device-free AbstractMesh across JAX versions.
-
-    Newer JAX takes ``(shape, names)``; older JAX takes a single tuple of
-    ``(name, size)`` pairs. Used by sharding-rule tests and dry-run tooling
-    that reason about placement without 512 real devices.
-    """
+    """Device-free AbstractMesh, for sharding-rule tests and dry-run
+    tooling that reason about placement without 512 real devices."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+    return AbstractMesh(tuple(shape), tuple(axis_names))
 
 
 def _mesh_axes_present(mesh: Mesh, axes):

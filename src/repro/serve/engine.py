@@ -475,15 +475,10 @@ class ContinuousBatchingEngine:
                         f"sharded serving splits KV heads over the model "
                         f"axis: num_kv_heads={cfg.num_kv_heads} is not "
                         f"divisible by mesh model dim {shape[1]}")
-                if len(jax.devices()) < ndev:
-                    raise ValueError(
-                        f"mesh_shape {shape} needs {ndev} devices, found "
-                        f"{len(jax.devices())} — set XLA_FLAGS="
-                        f"--xla_force_host_platform_device_count={ndev} "
-                        "before any jax import")
-                from repro.launch.mesh import _make_mesh
-                self.mesh = _make_mesh(shape, ("data", "model"),
-                                       jax.devices()[:ndev])
+                from repro.launch.mesh import _make_mesh, require_devices
+                self.mesh = _make_mesh(
+                    shape, ("data", "model"),
+                    require_devices(ndev, f"mesh_shape {shape}"))
                 self._tp_axis = "model"
                 self.tp = shape[1]
         # layer-fused megakernel: the whole attention-only decoder step —
@@ -547,7 +542,8 @@ class ContinuousBatchingEngine:
         # install / restore copies the whole multi-layer page pool, which
         # would cancel the paged-cache footprint win. CPU has no donation
         # (it only warns), so gate on backend. _extract must NOT donate —
-        # the cache lives on after a snapshot.
+        # the cache lives on after a snapshot. Only the cache is donated:
+        # a snapshot or prefill cache has no output to alias into.
         cpu = jax.default_backend() == "cpu"
         if self.tiered:
             # every step function threads the shared per-page format-id
@@ -609,10 +605,10 @@ class ContinuousBatchingEngine:
         self._install = jax.jit(
             lambda c, pf, slot, ids: kv_cache.install_prefill(
                 c, pf, slot, ids, ps),
-            donate_argnums=() if cpu else (0, 1))
+            donate_argnums=() if cpu else (0,))
         self._extract = jax.jit(kv_cache.extract_seq)
         self._restore = jax.jit(kv_cache.restore_seq,
-                                donate_argnums=() if cpu else (0, 1))
+                                donate_argnums=() if cpu else (0,))
         self._copy_page = jax.jit(kv_cache.copy_page,
                                   donate_argnums=() if cpu else (0,))
         # monolithic-path trace caches, LRU-bounded (satellite of the
@@ -689,7 +685,7 @@ class ContinuousBatchingEngine:
             if self.mesh is not None:
                 from jax.sharding import PartitionSpec as P
 
-                from repro.parallel.ctx import shard_map_compat, use_serve_tp
+                from repro.parallel.ctx import use_serve_tp
                 axis = self._tp_axis
 
                 def _sharded_step(p, c, *rest):
@@ -708,7 +704,7 @@ class ContinuousBatchingEngine:
                 n_meta = 10 + (1 if self.tiered else 0)
                 out_specs = ((P(), P(), P(), self._pool_specs) if rk
                              else (P(), self._pool_specs))
-                fn = shard_map_compat(
+                fn = jax.shard_map(
                     _sharded_step, mesh=self.mesh,
                     in_specs=(self._param_specs, self._pool_specs)
                     + (P(),) * n_meta,
@@ -822,11 +818,7 @@ class ContinuousBatchingEngine:
     # -- internals ----------------------------------------------------------
 
     def _shard_put(self, tree, specs):
-        """Place ``tree`` per a matching PartitionSpec tree on the mesh.
-
-        Flattened with ``flatten_up_to`` so the spec tree's P entries are
-        treated as leaves even on JAX versions where PartitionSpec is
-        itself a pytree container (it subclasses tuple on some)."""
+        """Place ``tree`` per a matching PartitionSpec tree on the mesh."""
         from jax.sharding import NamedSharding
 
         flat, treedef = jax.tree_util.tree_flatten(tree)
@@ -1053,8 +1045,7 @@ class ContinuousBatchingEngine:
                 # are replicated, no collective anywhere
                 from jax.sharding import PartitionSpec as P
 
-                from repro.parallel.ctx import shard_map_compat
-                run_fn = shard_map_compat(
+                run_fn = jax.shard_map(
                     run, mesh=self.mesh,
                     in_specs=(self._pool_specs, P(), P(), P()),
                     out_specs=self._pool_specs, check_vma=False)
@@ -1217,7 +1208,7 @@ class ContinuousBatchingEngine:
                             kv_cache.install_prefill_offset(
                                 c, pf, slot, ids, ps_, off, nr),
                             donate_argnums=()
-                            if jax.default_backend() == "cpu" else (0, 1)))
+                            if jax.default_backend() == "cpu" else (0,)))
                     self.cache = install(
                         self.cache, pfcache,
                         jnp.asarray(seq.slot, jnp.int32),

@@ -23,7 +23,7 @@ Split of responsibilities:
 The model-level cache pytree (``model.init_paged_cache``) interleaves two
 kinds of per-block caches; they are told apart structurally:
   * page pools: dicts with "k"/"v" (wide) or "k_elems"/… (MX) leaves
-    shaped (NP, PS, KVH, ·), with a leading num_groups axis inside
+    shaped (NP, KVH, PS, ·), with a leading num_groups axis inside
     ``cache["groups"]``;
   * recurrent state: any other dict; leaves have the slot axis first
     (again +1 leading group axis inside ``groups``).
@@ -257,7 +257,8 @@ def _set_block(cache, path, new_blk):
 
 
 def _install_pool(pool, contig, page_ids, page_size, grouped):
-    """Scatter a (1, T, ·) contiguous cache into pool pages ``page_ids``."""
+    """Scatter a (1, T, KVH, ·) contiguous cache into KV-head-major pool
+    pages ``page_ids``."""
     n = page_ids.shape[0]
     new = {}
     for key in pool:
@@ -265,10 +266,10 @@ def _install_pool(pool, contig, page_ids, page_size, grouped):
         if grouped:
             g = src.shape[0]
             pages = src.reshape(g, n, page_size, *src.shape[3:])
-            new[key] = pool[key].at[:, page_ids].set(pages)
+            new[key] = pool[key].at[:, page_ids].set(pages.swapaxes(2, 3))
         else:
             pages = src.reshape(n, page_size, *src.shape[2:])
-            new[key] = pool[key].at[page_ids].set(pages)
+            new[key] = pool[key].at[page_ids].set(pages.swapaxes(1, 2))
     return new
 
 
@@ -326,11 +327,15 @@ def install_prefill_offset(cache, prefill_cache, slot, page_ids,
         src = prefill_cache[path[0]] if len(path) == 1 else \
             prefill_cache["groups"][path[1]]
         if _is_pool(blk):
+            # pools are (.., NP, KVH, PS, ·): [pidx, :, sidx] addresses
+            # (rows, KVH, ·); with the group axis in front, the indexed
+            # dims lead the result, so rows go first there too
             if grouped:
-                blk = {key: blk[key].at[:, pidx, sidx].set(
-                    src[key][:, 0, :num_rows]) for key in blk}
+                blk = {key: blk[key].at[:, pidx, :, sidx].set(
+                    src[key][:, 0, :num_rows].swapaxes(0, 1))
+                    for key in blk}
             else:
-                blk = {key: blk[key].at[pidx, sidx].set(
+                blk = {key: blk[key].at[pidx, :, sidx].set(
                     src[key][0, :num_rows]) for key in blk}
         else:
             blk = _install_state(blk, src, slot, grouped)
@@ -371,8 +376,8 @@ def extract_seq(cache, slot, page_ids):
     over the MX cache, and the token stream could diverge.
 
     Returns a pytree mirroring ``cache`` with pool leaves gathered to
-    (n_pages, PS, ·) (grouped: (G, n_pages, PS, ·)) and state leaves
-    sliced to the slot row.
+    (n_pages, KVH, PS, ·) (grouped: (G, n_pages, KVH, PS, ·)) and state
+    leaves sliced to the slot row.
     """
     out = {}
     for path, blk, grouped in _iter_blocks(cache):
@@ -443,8 +448,8 @@ def pool_specs(cache, axis: str):
     """PartitionSpec pytree sharding every pool leaf's KV-head axis.
 
     The sharded serve engine partitions each attention layer's page pool
-    along its KV-head dimension — layout ``(NP, PS, KVH, ·)``, grouped
-    ``(G, NP, PS, KVH, ·)``, so the KV-head axis is always ``ndim - 2``.
+    along its KV-head dimension — layout ``(NP, KVH, PS, ·)``, grouped
+    ``(G, NP, KVH, PS, ·)``, so the KV-head axis is always ``ndim - 3``.
     The megakernel's stacked-layer pool (``model.init_megakernel_cache``)
     is the grouped layout with ``G == num_layers``, so these specs — and
     every other structural walk in this module (copy_page,
@@ -469,7 +474,7 @@ def pool_specs(cache, axis: str):
             # canonical (trimmed) form the step's outputs come back
             # with, and a P(..., axis, None) _shard_put placement would
             # make the first call a second trace
-            new = {key: P(*([None] * (leaf.ndim - 2)), axis)
+            new = {key: P(*([None] * (leaf.ndim - 3)), axis)
                    for key, leaf in blk.items()}
         else:
             new = jax.tree_util.tree_map(lambda leaf: P(), blk)

@@ -12,13 +12,12 @@ if str(_SRC) not in sys.path:
     sys.path.insert(0, str(_SRC))
 
 # Persistent XLA compilation cache: the suite is compile-dominated on CPU,
-# so repeat runs (local dev, CI re-runs) skip most XLA work. Repo-local and
-# gitignored; harmless if the backend doesn't support it.
-try:
-    import jax
+# so repeat runs (local dev, CI re-runs) skip most XLA work. Placed by the
+# same rule as every entry point (JAX_COMPILATION_CACHE_DIR, else the
+# checkout's gitignored .jax_cache/).
+import jax  # noqa: E402
 
-    jax.config.update("jax_compilation_cache_dir",
-                      str(_ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:  # pragma: no cover - cache is best-effort
-    pass
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

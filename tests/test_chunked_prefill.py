@@ -50,10 +50,10 @@ def _chunked_prefill_case(fmt, block_size, d, ps, pmax, prompt_len, chunk,
     ed = d // 2 if fmt_packed else d
     edt = jnp.uint8 if fmt_packed else (
         jnp.float8_e5m2 if fmt == "fp8_e5m2" else jnp.float8_e4m3fn)
-    pools = [jnp.zeros((npg, ps, kvh, ed), edt),
-             jnp.zeros((npg, ps, kvh, d // block_size), jnp.uint8),
-             jnp.zeros((npg, ps, kvh, ed), edt),
-             jnp.zeros((npg, ps, kvh, d // block_size), jnp.uint8)]
+    pools = [jnp.zeros((npg, kvh, ps, ed), edt),
+             jnp.zeros((npg, kvh, ps, d // block_size), jnp.uint8),
+             jnp.zeros((npg, kvh, ps, ed), edt),
+             jnp.zeros((npg, kvh, ps, d // block_size), jnp.uint8)]
     perm = rng.permutation(npg)
     need = -(-prompt_len // ps)
     table_np = np.full((1, pmax), -1, np.int32)
@@ -64,8 +64,8 @@ def _chunked_prefill_case(fmt, block_size, d, ps, pmax, prompt_len, chunk,
         real = min(chunk, prompt_len - start)
         out, pools, vis = mx_attention_prefill_fused(
             jnp.asarray(qw[:, :, start:start + chunk]),
-            jnp.asarray(kw[:, start:start + chunk]),
-            jnp.asarray(vw[:, start:start + chunk]),
+            jnp.asarray(kw[:, start:start + chunk].swapaxes(1, 2)),
+            jnp.asarray(vw[:, start:start + chunk].swapaxes(1, 2)),
             *pools, table, jnp.asarray([start], jnp.int32),
             jnp.asarray([start + real], jnp.int32), fmt_name=fmt,
             block_size=block_size, window=window, debug_visits=True)
@@ -94,7 +94,7 @@ def test_prefill_kernel_page_bytes_bit_identical_to_host_quantize(
         for pool_leaf, src in [(ke, kq.elements), (ks, kq.scales),
                                (ve, vq.elements), (vs, vq.scales)]:
             np.testing.assert_array_equal(
-                pool_leaf[table[0, pg]].astype(np.float32),
+                pool_leaf[table[0, pg]].swapaxes(0, 1).astype(np.float32),
                 np.asarray(src).astype(np.float32)[rows])
 
 

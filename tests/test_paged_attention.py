@@ -53,14 +53,13 @@ def _paged_layout(kq, vq, b, kvh, t, ps, rng):
     for name, src in [("ke", kq.elements), ("ks", kq.scales),
                       ("ve", vq.elements), ("vs", vq.scales)]:
         src = np.asarray(src)
-        pool = np.full((pool_pages, ps, kvh, src.shape[-1]), 255,
+        pool = np.full((pool_pages, kvh, ps, src.shape[-1]), 255,
                        dtype=src.dtype if src.dtype != np.uint8 else np.uint8)
         if pool.dtype != np.uint8:
             pool[:] = 0
         for i in range(b):
             for p in range(npg):
-                pool[table[i, p]] = src[i, :, p * ps:(p + 1) * ps].transpose(
-                    1, 0, 2)
+                pool[table[i, p]] = src[i, :, p * ps:(p + 1) * ps]
         arrs[name] = jnp.asarray(pool)
     return arrs, jnp.asarray(table)
 
@@ -302,10 +301,10 @@ def test_fused_never_materializes_gathered_cache():
 
     jaxpr = jax.make_jaxpr(run)(
         jnp.zeros((b, kvh, g, d), jnp.float32),
-        jnp.zeros((npg, ps, kvh, d), jnp.float8_e4m3fn),
-        jnp.zeros((npg, ps, kvh, 1), jnp.uint8),
-        jnp.zeros((npg, ps, kvh, d), jnp.float8_e4m3fn),
-        jnp.zeros((npg, ps, kvh, 1), jnp.uint8),
+        jnp.zeros((npg, kvh, ps, d), jnp.float8_e4m3fn),
+        jnp.zeros((npg, kvh, ps, 1), jnp.uint8),
+        jnp.zeros((npg, kvh, ps, d), jnp.float8_e4m3fn),
+        jnp.zeros((npg, kvh, ps, 1), jnp.uint8),
         jnp.zeros((b, pmax), jnp.int32),
         jnp.zeros((b,), jnp.int32))
     pallas_calls = 0

@@ -37,15 +37,15 @@ from repro.serve import ContinuousBatchingEngine, ServeConfig
 def _scatter_rows(pool, table_row, quant, lo, hi, ps):
     """Write contiguous token rows [lo, hi) of one sequence into `pool`.
 
-    quant.elements/.scales are (KVH, T, ·); pool pages are (PS, KVH, ·).
+    quant.elements/.scales are (KVH, T, ·); pool pages are (KVH, PS, ·).
     """
     el = np.asarray(quant.elements)
     sc = np.asarray(quant.scales)
     ke, ks = pool
     for t in range(lo, hi):
         pg = table_row[t // ps]
-        ke[pg, t % ps] = el[:, t]
-        ks[pg, t % ps] = sc[:, t]
+        ke[pg, :, t % ps] = el[:, t]
+        ks[pg, :, t % ps] = sc[:, t]
 
 
 def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
@@ -78,8 +78,8 @@ def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
         q_ = quantize(jnp.asarray(cache), fmt, block_size)
         el = np.asarray(q_.elements).reshape(kvh, npages, ps, -1)
         sc = np.asarray(q_.scales).reshape(kvh, npages, ps, -1)
-        return (np.ascontiguousarray(el.transpose(1, 2, 0, 3)),
-                np.ascontiguousarray(sc.transpose(1, 2, 0, 3)))
+        return (np.ascontiguousarray(el.transpose(1, 0, 2, 3)),
+                np.ascontiguousarray(sc.transpose(1, 0, 2, 3)))
 
     decoy = rng.normal(size=(kvh, npages * ps, d)).astype(np.float32)
     ke0, ks0 = _pool_from(decoy)
@@ -105,12 +105,12 @@ def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
         _scatter_rows((have[2], have[3]), table[i], vq[i], 0, starts[i], ps)
 
     q = rng.normal(size=(r, kvh, w, g, d)).astype(np.float32)
-    k_new = rng.normal(size=(r, w, kvh, d)).astype(np.float32)  # padding
-    v_new = rng.normal(size=(r, w, kvh, d)).astype(np.float32)
+    k_new = rng.normal(size=(r, kvh, w, d)).astype(np.float32)  # padding
+    v_new = rng.normal(size=(r, kvh, w, d)).astype(np.float32)
     for i in range(r):
         for t in range(n_news[i]):
-            k_new[i, t] = caches[i][0][:, starts[i] + t]
-            v_new[i, t] = caches[i][1][:, starts[i] + t]
+            k_new[i, :, t] = caches[i][0][:, starts[i] + t]
+            v_new[i, :, t] = caches[i][1][:, starts[i] + t]
 
     out, pools, visits = mx_attention_ragged_fused(
         jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
@@ -135,8 +135,8 @@ def test_ragged_kernel_bit_matches_split_oracle(fmt, block_size):
             pg, prow = table[i, t // ps], t % ps
             for got, exp in zip(pools, want):
                 np.testing.assert_array_equal(
-                    got[pg, prow].view(np.uint8),
-                    exp[pg, prow].view(np.uint8))
+                    got[pg, :, prow].view(np.uint8),
+                    exp[pg, :, prow].view(np.uint8))
     owned = {int(table[i, p]) for i in range(len(starts))
              for p in range(starts[i] // ps, -(-totals[i] // ps))}
     for pg in range(pools[0].shape[0]):
@@ -193,14 +193,14 @@ def test_ragged_kernel_inactive_rows_only_touch_trash_page():
     qd = quantize(jnp.asarray(decoy), "fp8_e4m3", 32)
     el = np.asarray(qd.elements).reshape(kvh, npages, ps, -1)
     sc = np.asarray(qd.scales).reshape(kvh, npages, ps, -1)
-    ke = np.ascontiguousarray(el.transpose(1, 2, 0, 3))
-    ks = np.ascontiguousarray(sc.transpose(1, 2, 0, 3))
+    ke = np.ascontiguousarray(el.transpose(1, 0, 2, 3))
+    ks = np.ascontiguousarray(sc.transpose(1, 0, 2, 3))
     pools = [ke, ks, ke.copy(), ks.copy()]
     table = np.full((1, 3), -1, np.int32)
     out, new_pools = mx_attention_ragged_fused(
         jnp.asarray(rng.normal(size=(1, kvh, w, g, d)).astype(np.float32)),
-        jnp.asarray(rng.normal(size=(1, w, kvh, d)).astype(np.float32)),
-        jnp.asarray(rng.normal(size=(1, w, kvh, d)).astype(np.float32)),
+        jnp.asarray(rng.normal(size=(1, kvh, w, d)).astype(np.float32)),
+        jnp.asarray(rng.normal(size=(1, kvh, w, d)).astype(np.float32)),
         *(jnp.asarray(a) for a in pools), jnp.asarray(table),
         jnp.asarray([0], jnp.int32), jnp.asarray([1], jnp.int32),
         fmt_name="fp8_e4m3", block_size=32)

@@ -95,11 +95,12 @@ def test_pool_specs_shard_kv_head_axis_only():
         specs, is_leaf=lambda x: isinstance(x, P))
     assert len(flat_c) == len(flat_s)
     for leaf, spec in zip(flat_c, flat_s):
-        # KVH is always ndim-2 of a pool leaf; NP and the storage dim
-        # stay unsharded so page gathers remain shard-local
-        assert spec[leaf.ndim - 2] == "model"
+        # KVH is always ndim-3 of a pool leaf (KV-head-major pages); NP,
+        # PS and the storage dim stay unsharded so page gathers remain
+        # shard-local
+        assert spec[leaf.ndim - 3] == "model"
         assert all(e is None for i, e in enumerate(spec)
-                   if i != leaf.ndim - 2)
+                   if i != leaf.ndim - 3)
 
 
 def test_serve_param_specs_shard_qkv_replicate_wo():

@@ -70,8 +70,8 @@ def _host_requant(rows_bytes, scales, src_fmt, dst_fmt, bs):
 def _fresh_pools(rng, npages=6, ps=4, kvh=2, d=32, bs=16):
     """uint8 tiered pools with every page holding fp8-encoded content."""
     nb = d // bs
-    ke = np.zeros((npages, ps, kvh, d), np.uint8)
-    ks = np.zeros((npages, ps, kvh, nb), np.uint8)
+    ke = np.zeros((npages, kvh, ps, d), np.uint8)
+    ks = np.zeros((npages, kvh, ps, nb), np.uint8)
     ve = np.zeros_like(ke)
     vs = np.zeros_like(ks)
     for elems, sc in ((ke, ks), (ve, vs)):
@@ -79,9 +79,9 @@ def _fresh_pools(rng, npages=6, ps=4, kvh=2, d=32, bs=16):
             for h in range(kvh):
                 wide = rng.normal(size=(ps, d)).astype(np.float32) * 3.0
                 q_e, q_s = _quantize_rows(jnp.asarray(wide), "fp8_e4m3", bs)
-                elems[p, :, h, :] = np.asarray(
+                elems[p, h] = np.asarray(
                     jax.lax.bitcast_convert_type(q_e, jnp.uint8))
-                sc[p, :, h, :] = np.asarray(q_s)
+                sc[p, h] = np.asarray(q_s)
     return tuple(jnp.asarray(a) for a in (ke, ks, ve, vs)), bs
 
 
@@ -101,21 +101,21 @@ def test_repack_kernel_matches_host_requant(dst):
     out = [np.asarray(a) for a in _repack(pools, [1, 3], [0, 0], 2, dst, bs)]
     w = F.get_format(dst).storage_len(before[0].shape[-1])
     for p in range(before[0].shape[0]):
-        for h in range(before[0].shape[2]):
+        for h in range(before[0].shape[1]):
             for e_i, s_i in ((0, 1), (2, 3)):
-                got_e, got_s = out[e_i][p, :, h, :], out[s_i][p, :, h, :]
+                got_e, got_s = out[e_i][p, h], out[s_i][p, h]
                 if p in (1, 3):
                     want_e, want_s = _host_requant(
-                        before[e_i][p, :, h, :], before[s_i][p, :, h, :],
+                        before[e_i][p, h], before[s_i][p, h],
                         "fp8_e4m3", dst, bs)
                     np.testing.assert_array_equal(got_e[:, :w], want_e)
                     np.testing.assert_array_equal(got_e[:, w:], 0)
                     np.testing.assert_array_equal(got_s, want_s)
                 else:  # untouched pages stay byte-identical
                     np.testing.assert_array_equal(got_e,
-                                                  before[e_i][p, :, h, :])
+                                                  before[e_i][p, h])
                     np.testing.assert_array_equal(got_s,
-                                                  before[s_i][p, :, h, :])
+                                                  before[s_i][p, h])
 
 
 def test_repack_kernel_mixed_source_formats():
@@ -128,14 +128,14 @@ def test_repack_kernel_mixed_source_formats():
         pools, [3, 4], [F.FORMAT_IDS["fp6_e3m2"], 0], 2, "fp4_e2m1", bs)]
     w = F.get_format("fp4_e2m1").storage_len(mid[0].shape[-1])
     for p, src in ((3, "fp6_e3m2"), (4, "fp8_e4m3")):
-        for h in range(mid[0].shape[2]):
+        for h in range(mid[0].shape[1]):
             for e_i, s_i in ((0, 1), (2, 3)):
                 want_e, want_s = _host_requant(
-                    mid[e_i][p, :, h, :], mid[s_i][p, :, h, :], src,
+                    mid[e_i][p, h], mid[s_i][p, h], src,
                     "fp4_e2m1", bs)
-                np.testing.assert_array_equal(out[e_i][p, :, h, :w], want_e)
-                np.testing.assert_array_equal(out[e_i][p, :, h, w:], 0)
-                np.testing.assert_array_equal(out[s_i][p, :, h, :], want_s)
+                np.testing.assert_array_equal(out[e_i][p, h, :, :w], want_e)
+                np.testing.assert_array_equal(out[e_i][p, h, :, w:], 0)
+                np.testing.assert_array_equal(out[s_i][p, h], want_s)
 
 
 def test_repack_widening_is_lossless():
@@ -146,11 +146,11 @@ def test_repack_widening_is_lossless():
     narrow = [np.asarray(a) for a in pools]
     out = [np.asarray(a) for a in _repack(
         pools, [2], [F.FORMAT_IDS["fp4_e2m1"]], 1, "fp8_e4m3", bs)]
-    for h in range(narrow[0].shape[2]):
+    for h in range(narrow[0].shape[1]):
         for e_i, s_i in ((0, 1), (2, 3)):
-            want = _host_decode(narrow[e_i][2, :, h, :],
-                                narrow[s_i][2, :, h, :], "fp4_e2m1", bs)
-            got = _host_decode(out[e_i][2, :, h, :], out[s_i][2, :, h, :],
+            want = _host_decode(narrow[e_i][2, h],
+                                narrow[s_i][2, h], "fp4_e2m1", bs)
+            got = _host_decode(out[e_i][2, h], out[s_i][2, h],
                                "fp8_e4m3", bs)
             np.testing.assert_array_equal(got, want)
 
