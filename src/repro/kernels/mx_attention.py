@@ -1347,6 +1347,7 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mx_attn_ragged",  # the HLO instruction's and trace's name
     )(*scalar_ops, qr, k_new, v_new,
       ke_pool, ks_pool, ve_pool, vs_pool)
     out = out.reshape(r, kvh, w, g, d)

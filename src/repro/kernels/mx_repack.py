@@ -142,4 +142,5 @@ def mx_repack_pages(ke_pool, ks_pool, ve_pool, vs_pool, page_ids, src_fmts,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="mx_repack_pages",  # the HLO instruction's and trace's name
     )(ids, fmts, cnt, ke_pool, ks_pool, ve_pool, vs_pool)

@@ -33,6 +33,7 @@ from repro.launch.compile_cache import setup_compile_cache
 from repro.nn import model
 from repro.serve import (AsyncServeEngine, FixedSlotEngine, ServeConfig,
                          ServeEngine, ServeHTTPServer, TierPolicy)
+from repro.serve.steplog import phase_means_ms
 
 log = logging.getLogger("repro.serve")
 
@@ -342,8 +343,16 @@ def main(argv=None):
                          "carrying dispatch)",
                          stats["pallas_calls_per_step"],
                          stats["prefill_rows_per_step"])
+        phases = phase_means_ms(engine.step_log)
+        if phases is not None:
+            log.info("host time per dispatching step (ms, mean of the "
+                     "last %d steps): %s; wall %.3f, host %.3f",
+                     len(engine.step_log), ", ".join(
+                         f"{p} {v:.3f}" for p, v in phases.items()
+                         if p not in ("host", "wall")),
+                     phases["wall"], phases["host"])
         if "admission_latency_p95" in stats:
-            log.info("admission latency (submit -> first token): "
+            log.info("admission latency (arrival -> first token): "
                      "p50 %.3fs p95 %.3fs mean %.3fs over %d requests "
                      "(%s prefill, %d chunks, %d live prefill traces)",
                      stats["admission_latency_p50"],

@@ -300,11 +300,16 @@ def _project_decode_qkv(params, x, posv, cfg: AttnConfig,
     reshape must follow the local width."""
     b, s = x.shape[:2]
     d = cfg.head_dim
-    q = linear.apply(params["wq"], x, quant, compute_dtype).reshape(b, s, -1, d)
-    k = linear.apply(params["wk"], x, quant, compute_dtype).reshape(b, s, -1, d)
-    v = linear.apply(params["wv"], x, quant, compute_dtype).reshape(b, s, -1, d)
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    with jax.named_scope("qkv_proj"):
+        q = linear.apply(params["wq"], x, quant,
+                         compute_dtype).reshape(b, s, -1, d)
+        k = linear.apply(params["wk"], x, quant,
+                         compute_dtype).reshape(b, s, -1, d)
+        v = linear.apply(params["wv"], x, quant,
+                         compute_dtype).reshape(b, s, -1, d)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, posv, cfg.rope_theta)
+        k = apply_rope(k, posv, cfg.rope_theta)
     return q, k, v
 
 
@@ -652,31 +657,35 @@ def apply_ragged(params, x, pool, page_rows, row_start, seq_lens,
     posv = row_start[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
     q, k, v = _project_decode_qkv(params, x, posv, cfg, quant,
                                   compute_dtype)
-    # local head counts (== cfg's when unsharded; the device's slice
-    # under serve TP — heads are laid out KV-major, so contiguous q-head
-    # shards align with contiguous KV-head shards)
-    kvh = k.shape[2]
-    g = q.shape[2] // kvh
-    qk = q.reshape(r, w, kvh, g, d).transpose(0, 2, 1, 3, 4)
-    out, (ke, ks, ve, vs) = mx_attention_ragged_fused(
-        qk, k.swapaxes(1, 2), v.swapaxes(1, 2), pool["k_elems"],
-        pool["k_scales"], pool["v_elems"],
-        pool["v_scales"], page_rows, row_start,
-        jnp.asarray(seq_lens, jnp.int32),
-        fmt_name=quant.fmt, block_size=min(quant.block_size, d),
-        softcap=cfg.softcap, window=cfg.window,
-        page_fmts=page_fmts, mixed_fmts=mixed_fmts)
-    pool = dict(pool, k_elems=ke, k_scales=ks, v_elems=ve, v_scales=vs)
-    axis = serve_tp_axis()
-    if axis is not None:
-        # (R, KVH/M, W, G, D) -> (R, KVH, W, G, D): the step's one
-        # collective; per-(row, kv-head) online softmax is independent,
-        # so the gathered tensor is exactly the unsharded kernel output
-        out = jax.lax.all_gather(out, axis, axis=1, tiled=True)
-    out = out.transpose(0, 2, 1, 3, 4)
-    out = out.reshape(r, w, -1).astype(compute_dtype)
-    y = linear.apply(params["wo"], out, quant,
-                     compute_dtype, tp_on="in")
+    with jax.named_scope("ragged_attn"):
+        # local head counts (== cfg's when unsharded; the device's slice
+        # under serve TP — heads are laid out KV-major, so contiguous
+        # q-head shards align with contiguous KV-head shards)
+        kvh = k.shape[2]
+        g = q.shape[2] // kvh
+        qk = q.reshape(r, w, kvh, g, d).transpose(0, 2, 1, 3, 4)
+        out, (ke, ks, ve, vs) = mx_attention_ragged_fused(
+            qk, k.swapaxes(1, 2), v.swapaxes(1, 2), pool["k_elems"],
+            pool["k_scales"], pool["v_elems"],
+            pool["v_scales"], page_rows, row_start,
+            jnp.asarray(seq_lens, jnp.int32),
+            fmt_name=quant.fmt, block_size=min(quant.block_size, d),
+            softcap=cfg.softcap, window=cfg.window,
+            page_fmts=page_fmts, mixed_fmts=mixed_fmts)
+        pool = dict(pool, k_elems=ke, k_scales=ks, v_elems=ve,
+                    v_scales=vs)
+        axis = serve_tp_axis()
+        if axis is not None:
+            # (R, KVH/M, W, G, D) -> (R, KVH, W, G, D): the step's one
+            # collective; per-(row, kv-head) online softmax is
+            # independent, so the gathered tensor is exactly the
+            # unsharded kernel output
+            out = jax.lax.all_gather(out, axis, axis=1, tiled=True)
+        out = out.transpose(0, 2, 1, 3, 4)
+        out = out.reshape(r, w, -1).astype(compute_dtype)
+    with jax.named_scope("o_proj"):
+        y = linear.apply(params["wo"], out, quant,
+                         compute_dtype, tp_on="in")
     return y, pool
 
 

@@ -17,6 +17,7 @@ def init(key, vocab: int, d_model: int, tied: bool = True):
     return params, axes
 
 
+@jax.named_scope("embed")
 def embed(params, tokens, scale_by_sqrt_dim: bool, compute_dtype=jnp.bfloat16):
     d = params["embed"].shape[-1]
     x = params["embed"].astype(compute_dtype)[tokens]
@@ -25,6 +26,7 @@ def embed(params, tokens, scale_by_sqrt_dim: bool, compute_dtype=jnp.bfloat16):
     return x
 
 
+@jax.named_scope("lm_head")
 def logits(params, x, softcap=None, compute_dtype=jnp.bfloat16):
     """Project hidden states to vocab logits (tied embedding transpose)."""
     if "head" in params:

@@ -23,6 +23,7 @@ def init(key, d_model: int, d_ff: int, kind: str = "swiglu"):
     return params, axes
 
 
+@jax.named_scope("ffn")
 def apply(params, x, quant: QuantConfig, kind: str = "swiglu",
           compute_dtype=jnp.bfloat16):
     up = linear.apply(params["up"], x, quant, compute_dtype)

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import QuantConfig, fake_quant, mx_dot, qat_matmul, quantize
@@ -62,9 +63,10 @@ def apply(params, x, quant: QuantConfig, compute_dtype=jnp.bfloat16,
         )
     else:
         # weight-only: straight-through fake-quantized weights, wide acts
-        wq = fake_quant(w.astype(jnp.float32), quant.fmt, quant.block_size, 0)
-        y = _dot_rounded(x.astype(compute_dtype), wq.astype(compute_dtype),
-                         compute_dtype)
+        with jax.named_scope("weight_quant"):
+            wq = fake_quant(w.astype(jnp.float32), quant.fmt,
+                            quant.block_size, 0).astype(compute_dtype)
+        y = _dot_rounded(x.astype(compute_dtype), wq, compute_dtype)
     return y.astype(compute_dtype)
 
 

@@ -123,7 +123,11 @@ def _walk_blocks(apply_fn, params, cfg: ModelConfig, x, cache):
             new.append(c)
         return x, tuple(new)
 
-    x, gcaches = jax.lax.scan(scan_fn, x, (params["groups"], cache["groups"]))
+    # the loop's own ops (its counter, the per-layer slices of the
+    # stacked weights and caches) carry the layer_loop scope
+    with jax.named_scope("layer_loop"):
+        x, gcaches = jax.lax.scan(scan_fn, x,
+                                  (params["groups"], cache["groups"]))
     cache["groups"] = gcaches
     for j, bd in enumerate(cfg.epilogue):
         key = f"epilogue{j}"
@@ -416,16 +420,17 @@ def ragged_step_paged(params, cfg: ModelConfig, cache, tokens, page_rows,
     # row-independent, so this is bit-identical to slicing afterwards);
     # out-of-range gather rows clamp onto the row's last real token, whose
     # duplicate logits the host ignores
-    last = jnp.maximum(seq_lens - row_start - 1, 0)[:, None]
-    idx = jnp.clip(jnp.asarray(logit_idx, jnp.int32)[:, None]
-                   + jnp.arange(num_logits, dtype=jnp.int32)[None, :],
-                   0, last)
-    x = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx[:, :, None], (r, num_logits, x.shape[-1])),
-        axis=1)
-    x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    logits = embedding.logits(params["embedding"], x, cfg.logit_softcap,
-                              cfg.compute_dtype)
+    with jax.named_scope("lm_head"):
+        last = jnp.maximum(seq_lens - row_start - 1, 0)[:, None]
+        idx = jnp.clip(jnp.asarray(logit_idx, jnp.int32)[:, None]
+                       + jnp.arange(num_logits, dtype=jnp.int32)[None, :],
+                       0, last)
+        x = jnp.take_along_axis(
+            x, jnp.broadcast_to(idx[:, :, None],
+                                (r, num_logits, x.shape[-1])), axis=1)
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        logits = embedding.logits(params["embedding"], x,
+                                  cfg.logit_softcap, cfg.compute_dtype)
     if cfg.num_codebooks > 1:
         logits = logits.reshape(r, num_logits, cfg.num_codebooks,
                                 cfg.vocab_size)

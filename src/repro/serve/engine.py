@@ -88,6 +88,7 @@ from .kv_cache import PAGE_UNITS_FULL, UNITS_BY_BITS
 from .overload import OverloadConfig, OverloadController
 from .sampling import SamplingParams
 from .scheduler import Scheduler
+from .steplog import STEP_LOG_LEN, StepTimer
 
 log = logging.getLogger("repro.serve")
 
@@ -581,8 +582,9 @@ class ContinuousBatchingEngine:
                   if fmts is not None else {})
             logits, c = model.decode_step_paged(
                 p, self.cfg_decode, c, tok, rows, pos, **kw)
-            toks = sampling.sample(logits[:, -1], temps, tps, tks, seeds,
-                                   ctrs)
+            with jax.named_scope("sample"):
+                toks = sampling.sample(logits[:, -1], temps, tps, tks,
+                                       seeds, ctrs)
             return toks, c
 
         def _verify_step(p, c, tok, rows, pos, temps, tps, tks, seeds,
@@ -591,8 +593,9 @@ class ContinuousBatchingEngine:
                   if fmts is not None else {})
             logits, c = model.verify_step_paged(
                 p, self.cfg_decode, c, tok, rows, pos, **kw)
-            n_emit, emitted = sampling.verify_rejection(
-                logits, tok[:, 1:], temps, tps, tks, seeds, ctrs)
+            with jax.named_scope("sample"):
+                n_emit, emitted = sampling.verify_rejection(
+                    logits, tok[:, 1:], temps, tps, tks, seeds, ctrs)
             return n_emit, emitted, c
 
         self._decode = jax.jit(_decode_step,
@@ -673,13 +676,14 @@ class ContinuousBatchingEngine:
                 logits, c = step_model(
                     p, self.cfg_decode, c, tok, rows, start, lens, lidx,
                     num_logits=nl, **kw)
-                toks = sampling.sample(logits[:, 0], temps, tps, tks,
-                                       seeds, ctrs)
-                if rk:
-                    n_emit, emitted = sampling.verify_rejection(
-                        logits, tok[:, 1:1 + rk], temps, tps, tks, seeds,
-                        ctrs)
-                    return toks, n_emit, emitted, c
+                with jax.named_scope("sample"):
+                    toks = sampling.sample(logits[:, 0], temps, tps, tks,
+                                           seeds, ctrs)
+                    if rk:
+                        n_emit, emitted = sampling.verify_rejection(
+                            logits, tok[:, 1:1 + rk], temps, tps, tks,
+                            seeds, ctrs)
+                        return toks, n_emit, emitted, c
                 return toks, c
 
             if self.mesh is not None:
@@ -718,6 +722,7 @@ class ContinuousBatchingEngine:
             # count, measured lazily at the first ragged step)
             self._ragged_fn_raw = _ragged_step_fn
         self.pallas_calls_per_step = None
+        self._step_shapes = None  # the ragged step's argument shapes
         self._key = jax.random.PRNGKey(0)
         # requests that don't carry SamplingParams sample with these
         self._default_sampling = SamplingParams(
@@ -734,7 +739,6 @@ class ContinuousBatchingEngine:
         # dispatches_per_mixed_step == 1 — is measured, never asserted
         self.dispatch_counts = {"decode": 0, "verify": 0, "prefill": 0,
                                 "ragged": 0, "write": 0, "repack": 0}
-        self.dispatches_last_step = 0
         self._step_dispatches = 0
         self.mixed_steps = 0  # steps doing decode AND prefill work
         self.mixed_step_dispatches = 0
@@ -745,13 +749,17 @@ class ContinuousBatchingEngine:
         self.prefill_chunks = 0  # per-sequence chunks processed
         self.prefill_dispatches = 0  # chunked-prefill kernel invocations
         self._rr_clock = 0  # cross-step round-robin cursor over prefills
-        # admission latency: wall seconds from submit() to the request's
-        # first sampled token (the serving-side tail-latency metric
-        # chunked prefill exists to improve). Bounded sliding window so a
-        # long-running server's stats stay O(1) memory — the same
-        # unbounded-growth class the LRU trace cap closes.
-        self._submit_time: Dict[int, float] = {}
+        # admission latency: seconds from a request's arrival (its
+        # caller's receipt time, else submit()) to its first sampled
+        # token (the serving-side tail-latency metric chunked prefill
+        # exists to improve). Bounded sliding window so a long-running
+        # server's stats stay O(1) memory — the same unbounded-growth
+        # class the LRU trace cap closes.
         self.admission_latencies: deque = deque(maxlen=4096)
+        # host time of each of the last STEP_LOG_LEN steps, by phase
+        # (serve.steplog); self._timer is the step in progress
+        self.step_log: deque = deque(maxlen=STEP_LOG_LEN)
+        self._timer: Optional[StepTimer] = None
         # speculative decoding stats
         self.spec_steps = 0  # verify steps run
         self.spec_seq_steps = 0  # (sequence, verify step) participations
@@ -904,11 +912,11 @@ class ContinuousBatchingEngine:
             "layer-fused megakernel" if self.megakernel
             else "per-layer ragged step")
 
-    def _record_first_token(self, req_id: int) -> None:
-        """Admission-latency sample: submit() -> first sampled token."""
-        t0 = self._submit_time.pop(req_id, None)
-        if t0 is not None:
-            lat = time.perf_counter() - t0
+    def _record_first_token(self, req) -> None:
+        """Admission-latency sample: arrival -> first sampled token."""
+        if req.first_token is None:
+            req.first_token = time.perf_counter()
+            lat = req.first_token - req.arrival
             self.admission_latencies.append(lat)
             self.overload.observe_first_token(lat)
 
@@ -943,7 +951,8 @@ class ContinuousBatchingEngine:
 
     def _sample_prefill_rows(self, seqs, logits):
         """Sample each row's first token from prefill logits (N, V) —
-        counter 0 of each request's stream; one dispatch per batch."""
+        counter 0 of each request's stream; one dispatch per batch, its
+        (N,) tokens left on the device."""
         n = len(seqs)
         temps = np.zeros((n,), np.float32)
         tps = np.ones((n,), np.float32)
@@ -954,10 +963,10 @@ class ContinuousBatchingEngine:
             temps[i], tps[i], tks[i] = sp.temperature, sp.top_p, sp.top_k
             seeds[i] = seq.req.seed
         self._count_dispatch("prefill")
-        return np.asarray(self._sample_fn(
+        return self._sample_fn(
             logits, jnp.asarray(temps), jnp.asarray(tps),
             jnp.asarray(tks), jnp.asarray(seeds),
-            jnp.zeros((n,), jnp.int32)))
+            jnp.zeros((n,), jnp.int32))
 
     # -- tiered mixed-format pool internals ---------------------------------
 
@@ -1125,12 +1134,15 @@ class ContinuousBatchingEngine:
         self.max_repacked_in_step = max(self.max_repacked_in_step,
                                         self._repacked_this_step)
 
-    def _admit(self):
+    def _admit(self) -> list:
+        """Admit what fits; returns the admitted request ids."""
         sched = self.scheduler
+        admitted = []
         while True:
             seq = sched.admit_next()
             if seq is None:
-                return
+                return admitted
+            admitted.append(seq.req.id)
             if seq.req.swap is not None:
                 # swapped-out sequence: restore the exact bytes of the
                 # pages it exclusively owned into their fresh replacements
@@ -1229,8 +1241,9 @@ class ContinuousBatchingEngine:
                     jnp.asarray(seq.pages, jnp.int32))
                 self._count_dispatch("write")
             sched.register_prefix(seq)
-            tok = int(self._sample_prefill_rows([seq], logits[:, -1])[0])
-            self._record_first_token(seq.req.id)
+            tok = int(np.asarray(
+                self._sample_prefill_rows([seq], logits[:, -1]))[0])
+            self._record_first_token(seq.req)
             sched.record_token(seq, tok, eos_id=self.serve_cfg.eos_id)
 
     def _run_prefill_chunks(self) -> None:
@@ -1275,49 +1288,57 @@ class ContinuousBatchingEngine:
         sched = self.scheduler
         c = self.serve_cfg.prefill_chunk
         bsz = len(seqs)
-        tokens = np.zeros((bsz, c), np.int32)
-        rows = np.full((bsz, sched.pages_per_slot), -1, np.int32)
-        starts = np.zeros((bsz,), np.int32)
-        reals = np.zeros((bsz,), np.int32)
-        for i, seq in enumerate(seqs):
-            prompt = seq.req.prompt
-            st = seq.prefill_pos
-            real = min(c, len(prompt) - st)
-            tokens[i, :real] = prompt[st:st + real]
-            rows[i, : len(seq.pages)] = seq.pages
-            starts[i], reals[i] = st, real
-        args = ()
-        if self.tiered:
-            self._drain_allocs()
-            ps = self.serve_cfg.page_size
+        phase = self._timer.phase
+        with phase("assemble"):
+            tokens = np.zeros((bsz, c), np.int32)
+            rows = np.full((bsz, sched.pages_per_slot), -1, np.int32)
+            starts = np.zeros((bsz,), np.int32)
+            reals = np.zeros((bsz,), np.int32)
             for i, seq in enumerate(seqs):
-                self._mark_write(seq.pages[starts[i] // ps:
-                                           (starts[i] + reals[i] - 1)
-                                           // ps + 1])
-            args = (self._sync_fmts(),)
-        logits, self.cache = self._prefill_chunk(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(rows), jnp.asarray(starts), jnp.asarray(reals),
-            jnp.asarray(reals - 1), *args)
-        self._count_dispatch("prefill")
-        self._step_had_prefill = True
-        self.prefill_tokens += int(reals.sum())
-        self.prefill_chunks += bsz
-        self.prefill_dispatches += 1
-        sampled = None
-        for i, seq in enumerate(seqs):
-            st, real = int(starts[i]), int(reals[i])
-            final = st + real >= len(seq.req.prompt)
-            seq.pos = st + real
-            seq.prefill_pos = st + c
-            if final:
-                seq.prefill_pos = None
-                sched.register_prefix(seq)
-                if sampled is None:
-                    sampled = self._sample_prefill_rows(seqs, logits[:, -1])
-                tok = int(sampled[i])
-                self._record_first_token(seq.req.id)
-                sched.record_token(seq, tok, eos_id=self.serve_cfg.eos_id)
+                prompt = seq.req.prompt
+                st = seq.prefill_pos
+                real = min(c, len(prompt) - st)
+                tokens[i, :real] = prompt[st:st + real]
+                rows[i, : len(seq.pages)] = seq.pages
+                starts[i], reals[i] = st, real
+            args = ()
+            if self.tiered:
+                self._drain_allocs()
+                ps = self.serve_cfg.page_size
+                for i, seq in enumerate(seqs):
+                    self._mark_write(seq.pages[starts[i] // ps:
+                                               (starts[i] + reals[i] - 1)
+                                               // ps + 1])
+                args = (self._sync_fmts(),)
+            call_args = (self.params, self.cache, jnp.asarray(tokens),
+                         jnp.asarray(rows), jnp.asarray(starts),
+                         jnp.asarray(reals), jnp.asarray(reals - 1), *args)
+        finals = starts + reals >= np.asarray(
+            [len(seq.req.prompt) for seq in seqs])
+        with phase("dispatch"):
+            logits, self.cache = self._prefill_chunk(*call_args)
+            self._count_dispatch("prefill")
+            # prompt-final rows sample their first token from their own
+            # logits row, in one more dispatch
+            sampled = (self._sample_prefill_rows(seqs, logits[:, -1])
+                       if finals.any() else None)
+        with phase("sync"):
+            if sampled is not None:
+                sampled = np.asarray(sampled)
+        with phase("record"):
+            self._step_had_prefill = True
+            self.prefill_tokens += int(reals.sum())
+            self.prefill_chunks += bsz
+            self.prefill_dispatches += 1
+            for i, seq in enumerate(seqs):
+                seq.pos = int(starts[i] + reals[i])
+                seq.prefill_pos = int(starts[i]) + c
+                if finals[i]:
+                    seq.prefill_pos = None
+                    sched.register_prefix(seq)
+                    self._record_first_token(seq.req)
+                    sched.record_token(seq, int(sampled[i]),
+                                       eos_id=self.serve_cfg.eos_id)
 
     def _swap_out(self, victim) -> None:
         """Preempt ``victim``: snapshot + free only the pages it
@@ -1472,31 +1493,40 @@ class ContinuousBatchingEngine:
         self._step_dispatches = 0
         self._step_had_prefill = False
         self._step_had_decode = False
+        self._tick += 1
+        self._timer = StepTimer(self._tick)
         try:
             return self._step_inner()
         finally:
-            self.dispatches_last_step = self._step_dispatches
+            self.step_log.append(
+                self._timer.close(self._step_dispatches > 0))
             if self._step_had_decode and self._step_had_prefill:
                 self.mixed_steps += 1
                 self.mixed_step_dispatches += self._step_dispatches
 
     def _step_inner(self) -> bool:
         sched = self.scheduler
-        self._tick += 1
-        self._admit()
+        phase = self._timer.phase
+        with phase("admit") as span:
+            admitted = self._admit()
+            if (not sched.active() and sched.queue
+                    and self._reclaim_swapped_refs()):
+                # pinned shared pages were the blocker
+                admitted += self._admit()
+            if admitted and span.is_enabled():
+                span.set_metadata(requests=" ".join(map(str, admitted)))
         if not sched.active():
-            if sched.queue and self._reclaim_swapped_refs():
-                self._admit()  # pinned shared pages were the blocker
-            if not sched.active():
-                if sched.queue:
-                    raise RuntimeError("scheduler stalled with queued work")
-                return sched.has_work
+            if sched.queue:
+                raise RuntimeError("scheduler stalled with queued work")
+            return sched.has_work
         if self.ragged:
-            self._run_repack()
+            with phase("repack"):
+                self._run_repack()
             self._ragged_step()
             return sched.has_work
         self._run_prefill_chunks()
-        self._run_repack()
+        with phase("repack"):
+            self._run_repack()
         if not sched.decode_ready():
             # every active sequence is still streaming its prompt; the
             # chunk(s) above were this step's progress
@@ -1504,21 +1534,26 @@ class ContinuousBatchingEngine:
         if self.spec_enabled:
             self._spec_step()
             return sched.has_work
-        self._ensure_pages()
-        tokens, pos, page_rows, act = sched.assemble()
-        args = (self._sync_fmts(),) if self.tiered else ()
-        toks_dev, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(page_rows), jnp.asarray(pos),
-            *self._slot_sampling(act), *args)
-        self._count_dispatch("decode")
-        self._step_had_decode = True
-        toks = np.asarray(toks_dev)
-        self.steps += 1
-        for seq in act:
-            sched.advance(seq)
-            sched.record_token(seq, int(toks[seq.slot]),
-                               eos_id=self.serve_cfg.eos_id)
+        with phase("ensure_pages"):
+            self._ensure_pages()
+        with phase("assemble"):
+            tokens, pos, page_rows, act = sched.assemble()
+            args = (self._sync_fmts(),) if self.tiered else ()
+            call_args = (self.params, self.cache, jnp.asarray(tokens),
+                         jnp.asarray(page_rows), jnp.asarray(pos),
+                         *self._slot_sampling(act), *args)
+        with phase("dispatch"):
+            toks_dev, self.cache = self._decode(*call_args)
+            self._count_dispatch("decode")
+        with phase("sync"):
+            toks = np.asarray(toks_dev)
+        with phase("record"):
+            self._step_had_decode = True
+            self.steps += 1
+            for seq in act:
+                sched.advance(seq)
+                sched.record_token(seq, int(toks[seq.slot]),
+                                   eos_id=self.serve_cfg.eos_id)
         return sched.has_work
 
     def _ragged_step(self) -> None:
@@ -1541,55 +1576,79 @@ class ContinuousBatchingEngine:
         """
         sched = self.scheduler
         k = self._ragged_k
-        self._ensure_pages(1 + k)
-        if self.tiered:
-            self._drain_allocs()
-            ps = self.serve_cfg.page_size
-            for seq in sched.prefilling():
-                st = seq.prefill_pos
-                # same formula assemble_ragged is about to apply — the
-                # pre-pass must mark exactly the pages the step writes
-                real = sched.planned_prefill_real(seq, self._ragged_width)
-                if real > 0:
-                    self._mark_write(
-                        seq.pages[st // ps: (st + real - 1) // ps + 1])
-        (tokens, row_start, seq_lens, logit_idx, page_rows, modes,
-         decode, prefill) = sched.assemble_ragged(self._ragged_width,
-                                                  extra_tokens=k)
-        if not decode and not prefill:
-            return
-        if k:
-            for seq in decode:
-                history = np.concatenate(
-                    [seq.req.prompt,
-                     np.asarray(seq.req.generated, np.int32)])
-                drafts = np.asarray(self.drafter.propose(history, k),
-                                    np.int32)
-                if drafts.shape != (k,):
-                    raise ValueError(
-                        f"drafter returned shape {drafts.shape}, "
-                        f"wanted ({k},)")
-                tokens[seq.slot, 1:1 + k] = drafts
-        # prefill-final rows sample at stream index 0 (len(generated) is
-        # 0), decode/verify rows at their next index — one parameter
-        # vector covers every mode
-        samp = self._slot_sampling(decode + [t[0] for t in prefill])
-        args = (self._sync_fmts(),) if self.tiered else ()
-        call_args = (self._step_params, self.cache, jnp.asarray(tokens),
-                     jnp.asarray(page_rows), jnp.asarray(row_start),
-                     jnp.asarray(seq_lens), jnp.asarray(logit_idx),
-                     *samp, *args)
-        if self.pallas_calls_per_step is None and self.mesh is None:
-            self._audit_dispatches(call_args)
-        out = self._ragged_fn(*call_args)
-        self._count_dispatch("ragged")
-        if k:
-            toks_dev, n_emit_dev, emitted_dev, self.cache = out
-            n_emit = np.asarray(n_emit_dev)
-            emitted = np.asarray(emitted_dev)
-        else:
-            toks_dev, self.cache = out
-        toks = np.asarray(toks_dev)
+        phase = self._timer.phase
+        with phase("ensure_pages"):
+            self._ensure_pages(1 + k)
+            if self.tiered:
+                self._drain_allocs()
+                ps = self.serve_cfg.page_size
+                for seq in sched.prefilling():
+                    st = seq.prefill_pos
+                    # same formula assemble_ragged is about to apply —
+                    # the pre-pass must mark exactly the pages the step
+                    # writes
+                    real = sched.planned_prefill_real(seq,
+                                                      self._ragged_width)
+                    if real > 0:
+                        self._mark_write(
+                            seq.pages[st // ps: (st + real - 1) // ps + 1])
+        with phase("assemble"):
+            (tokens, row_start, seq_lens, logit_idx, page_rows, modes,
+             decode, prefill) = sched.assemble_ragged(self._ragged_width,
+                                                      extra_tokens=k)
+            if not decode and not prefill:
+                return
+            if k:
+                for seq in decode:
+                    history = np.concatenate(
+                        [seq.req.prompt,
+                         np.asarray(seq.req.generated, np.int32)])
+                    drafts = np.asarray(self.drafter.propose(history, k),
+                                        np.int32)
+                    if drafts.shape != (k,):
+                        raise ValueError(
+                            f"drafter returned shape {drafts.shape}, "
+                            f"wanted ({k},)")
+                    tokens[seq.slot, 1:1 + k] = drafts
+            # prefill-final rows sample at stream index 0 (len(generated)
+            # is 0), decode/verify rows at their next index — one
+            # parameter vector covers every mode
+            samp = self._slot_sampling(decode + [t[0] for t in prefill])
+            args = (self._sync_fmts(),) if self.tiered else ()
+            call_args = (self._step_params, self.cache, jnp.asarray(tokens),
+                         jnp.asarray(page_rows), jnp.asarray(row_start),
+                         jnp.asarray(seq_lens), jnp.asarray(logit_idx),
+                         *samp, *args)
+        with phase("dispatch"):
+            if self._step_shapes is None:
+                # placed (committed) arrays keep their sharding, so that
+                # step_program() lowers exactly the dispatched program
+                self._step_shapes = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype,
+                        sharding=x.sharding if x.committed else None),
+                    call_args)
+            if self.pallas_calls_per_step is None and self.mesh is None:
+                self._audit_dispatches(call_args)
+            out = self._ragged_fn(*call_args)
+            self._count_dispatch("ragged")
+        with phase("sync"):
+            n_emit = emitted = None
+            if k:
+                toks_dev, n_emit_dev, emitted_dev, self.cache = out
+                n_emit = np.asarray(n_emit_dev)
+                emitted = np.asarray(emitted_dev)
+            else:
+                toks_dev, self.cache = out
+            toks = np.asarray(toks_dev)
+        with phase("record"):
+            self._record_ragged(toks, n_emit, emitted, decode, prefill)
+
+    def _record_ragged(self, toks, n_emit, emitted, decode, prefill) -> None:
+        """Book the ragged step's sampled tokens: decode and verify rows
+        advance and record theirs, prompt-final chunks their first."""
+        sched = self.scheduler
+        k = self._ragged_k
         if decode:
             self.steps += 1
             self._step_had_decode = True
@@ -1627,7 +1686,7 @@ class ContinuousBatchingEngine:
             seq.prefill_pos = None if final else st + real
             if final:
                 sched.register_prefix(seq)
-                self._record_first_token(seq.req.id)
+                self._record_first_token(seq.req)
                 sched.record_token(seq, int(toks[seq.slot]),
                                    eos_id=self.serve_cfg.eos_id)
 
@@ -1653,26 +1712,40 @@ class ContinuousBatchingEngine:
         """
         sched = self.scheduler
         k = self.serve_cfg.num_draft_tokens
-        self._ensure_pages(1 + k)
-        tokens, pos, page_rows, act = sched.assemble(extra_tokens=k)
-        for seq in act:
-            history = np.concatenate(
-                [seq.req.prompt,
-                 np.asarray(seq.req.generated, np.int32)])
-            drafts = np.asarray(self.drafter.propose(history, k), np.int32)
-            if drafts.shape != (k,):
-                raise ValueError(
-                    f"drafter returned shape {drafts.shape}, wanted ({k},)")
-            tokens[seq.slot, 1:] = drafts
-        args = (self._sync_fmts(),) if self.tiered else ()
-        n_emit_dev, emitted_dev, self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(page_rows), jnp.asarray(pos),
-            *self._slot_sampling(act), *args)
-        self._count_dispatch("verify")
+        phase = self._timer.phase
+        with phase("ensure_pages"):
+            self._ensure_pages(1 + k)
+        with phase("assemble"):
+            tokens, pos, page_rows, act = sched.assemble(extra_tokens=k)
+            for seq in act:
+                history = np.concatenate(
+                    [seq.req.prompt,
+                     np.asarray(seq.req.generated, np.int32)])
+                drafts = np.asarray(self.drafter.propose(history, k),
+                                    np.int32)
+                if drafts.shape != (k,):
+                    raise ValueError(f"drafter returned shape "
+                                     f"{drafts.shape}, wanted ({k},)")
+                tokens[seq.slot, 1:] = drafts
+            args = (self._sync_fmts(),) if self.tiered else ()
+            call_args = (self.params, self.cache, jnp.asarray(tokens),
+                         jnp.asarray(page_rows), jnp.asarray(pos),
+                         *self._slot_sampling(act), *args)
+        with phase("dispatch"):
+            n_emit_dev, emitted_dev, self.cache = self._verify(*call_args)
+            self._count_dispatch("verify")
+        with phase("sync"):
+            n_emit = np.asarray(n_emit_dev)
+            emitted = np.asarray(emitted_dev)
+        with phase("record"):
+            self._record_verify(act, n_emit, emitted)
+
+    def _record_verify(self, act, n_emit, emitted) -> None:
+        """Book a verify step: each row's accepted drafts and bonus
+        token, stopping at EOS / max_new (the rollback)."""
+        sched = self.scheduler
+        k = self.serve_cfg.num_draft_tokens
         self._step_had_decode = True
-        n_emit = np.asarray(n_emit_dev)
-        emitted = np.asarray(emitted_dev)
         self.steps += 1
         self.spec_steps += 1
         for seq in act:
@@ -1694,11 +1767,14 @@ class ContinuousBatchingEngine:
     # -- public API ---------------------------------------------------------
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
-               sampling_params: Optional[SamplingParams] = None) -> int:
+               sampling_params: Optional[SamplingParams] = None,
+               arrival: Optional[float] = None) -> int:
         """Queue one request; returns its id. Use with :meth:`run`.
 
         ``sampling_params`` overrides the engine-default temperature /
         top-p / top-k / seed for this request alone (None = defaults).
+        ``arrival`` is when the request arrived, in ``time.perf_counter``
+        seconds (default: now); admission latency counts from it.
         Raises :class:`~.overload.ShedError` when overload control is
         configured and admitting this request would already miss the
         SLO — shed at the door, before it costs a slot, pages, and
@@ -1709,10 +1785,8 @@ class ContinuousBatchingEngine:
               else self._default_sampling)
         seed = sampling.resolve_seed(sp, self.serve_cfg.seed,
                                      self.scheduler._next_id)
-        rid = self.scheduler.submit(prompt, max_new_tokens,
-                                    sampling=sp, seed=seed)
-        self._submit_time[rid] = time.perf_counter()
-        return rid
+        return self.scheduler.submit(prompt, max_new_tokens, sampling=sp,
+                                     seed=seed, arrival=arrival)
 
     def cancel(self, request_id: int) -> bool:
         """Abandon a request mid-flight (client disconnect): frees its
@@ -1721,10 +1795,8 @@ class ContinuousBatchingEngine:
         decoding, or swapped out. True if the request was found (False:
         it already finished and its resources are long gone)."""
         found = self.scheduler.cancel(request_id)
-        if found:
-            self._submit_time.pop(request_id, None)
-            if self.tiered:
-                self._swap_fmts.pop(request_id, None)
+        if found and self.tiered:
+            self._swap_fmts.pop(request_id, None)
         return found
 
     def save_prefix_cache(self, path) -> int:
@@ -1895,7 +1967,6 @@ class ContinuousBatchingEngine:
         total_dispatches = sum(self.dispatch_counts.values())
         stats.update({
             "dispatches_total": total_dispatches,
-            "dispatches_last_step": self.dispatches_last_step,
             "dispatches_per_step": (total_dispatches / self.steps
                                     if self.steps else 0.0),
             "mixed_steps": self.mixed_steps,
@@ -1957,6 +2028,18 @@ class ContinuousBatchingEngine:
         if sched.prefix is not None:
             stats.update(sched.prefix.stats())
         return stats
+
+    def step_program(self):
+        """The ragged step as compiled for the argument shapes it runs
+        at (a ``jax.stages.Compiled``: JAX keeps the executable it
+        dispatched with the lowering, so nothing compiles again), or None
+        before the first ragged dispatch. Its ``as_text()`` gives each
+        instruction's named scope (``embed``, ``qkv_proj``,
+        ``ragged_attn``, ``weight_quant``, ...) in its ``op_name``
+        metadata, under the instruction names a profiler trace shows."""
+        if self._step_shapes is None:
+            return None
+        return self._ragged_fn.lower(*self._step_shapes).compile()
 
 
 # the default engine: continuous batching over the paged MX cache

@@ -59,6 +59,7 @@ request, page_ids) admission tuples and assembles per-step numpy batches.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Callable, List, Optional
 
@@ -108,6 +109,12 @@ class Request:
     # bounded by Scheduler.max_deferrals so a preempted / stalled leader
     # can't starve it forever (it then prefills independently)
     defer_count: int = 0
+    # ``time.perf_counter`` seconds: when the request arrived (its
+    # caller's receipt time, else submit()), when it first got a slot,
+    # and when its first token was sampled (None until then)
+    arrival: float = 0.0
+    admitted: Optional[float] = None
+    first_token: Optional[float] = None
 
     @property
     def remaining(self) -> int:
@@ -207,9 +214,11 @@ class Scheduler:
     # -- submission ---------------------------------------------------------
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
-               sampling=None, seed: int = 0) -> int:
+               sampling=None, seed: int = 0,
+               arrival: Optional[float] = None) -> int:
         """Queue one request. Invalid inputs fail here, with a clear
-        ValueError, not steps later inside a jitted prefill."""
+        ValueError, not steps later inside a jitted prefill. ``arrival``
+        (``time.perf_counter`` seconds) defaults to now."""
         prompt = np.asarray(prompt)
         if not np.issubdtype(prompt.dtype, np.integer):
             raise ValueError(
@@ -240,7 +249,9 @@ class Scheduler:
                 f"end of this request would overflow its page table "
                 f"(shrink num_draft_tokens or raise max_seq)")
         req = Request(self._next_id, prompt, int(max_new_tokens),
-                      sampling=sampling, seed=int(seed))
+                      sampling=sampling, seed=int(seed),
+                      arrival=(time.perf_counter() if arrival is None
+                               else float(arrival)))
         self._next_id += 1
         self.queue.append(req)
         return req.id
@@ -406,6 +417,8 @@ class Scheduler:
             seq = self._try_admit(self.queue[qi], free_slots[0])
             if seq is not None:
                 del self.queue[qi]
+                if seq.req.admitted is None:
+                    seq.req.admitted = time.perf_counter()
                 if qi:
                     self.skipped_admissions += 1
                 return seq

@@ -44,6 +44,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -89,8 +90,11 @@ class AsyncServeEngine:
     # -- submission / delivery ----------------------------------------------
 
     def submit(self, prompt, max_new_tokens: int,
-               sampling_params: Optional[SamplingParams] = None) -> int:
+               sampling_params: Optional[SamplingParams] = None,
+               arrival: Optional[float] = None) -> int:
         """Queue one request; returns its id (tokens via :meth:`stream`).
+        ``arrival`` (``time.perf_counter`` seconds, default now) is when
+        the request was received; admission latency counts from it.
 
         Raises :class:`DrainingError` while draining and propagates the
         engine's :class:`~.overload.ShedError` under overload.
@@ -99,7 +103,8 @@ class AsyncServeEngine:
             raise DrainingError("server is draining, not accepting work")
         rid = self.engine.submit(np.asarray(prompt, np.int32),
                                  max_new_tokens,
-                                 sampling_params=sampling_params)
+                                 sampling_params=sampling_params,
+                                 arrival=arrival)
         self._queues[rid] = asyncio.Queue()
         self._kick()
         return rid
@@ -230,10 +235,11 @@ class ServeHTTPServer:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        received = time.perf_counter()
         try:
             method, path, body = await self._read_request(reader)
             if method == "POST" and path == "/v1/generate":
-                await self._generate(reader, writer, body)
+                await self._generate(reader, writer, body, received)
             elif method == "POST" and path == "/v1/cancel":
                 found = self.engine.cancel(int(body["request_id"]))
                 writer.write(_json_response(
@@ -286,11 +292,12 @@ class ServeHTTPServer:
         return method, path.strip(), body
 
     async def _generate(self, reader: asyncio.StreamReader,
-                        writer: asyncio.StreamWriter, body: dict) -> None:
+                        writer: asyncio.StreamWriter, body: dict,
+                        received: float) -> None:
         try:
             rid = self.engine.submit(
                 body["prompt"], int(body.get("max_new_tokens", 16)),
-                sampling_params=_parse_sampling(body))
+                sampling_params=_parse_sampling(body), arrival=received)
         except DrainingError as e:
             writer.write(_json_response("503 Service Unavailable",
                                         {"error": str(e)}))

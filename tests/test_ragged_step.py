@@ -27,6 +27,7 @@ from repro.kernels import (mx_attention_ragged_fused,
                            mx_attention_verify_fused)
 from repro.nn import BlockDef, ModelConfig, model
 from repro.serve import ContinuousBatchingEngine, ServeConfig
+from repro.serve.steplog import phase_means_ms
 
 
 # ---------------------------------------------------------------------------
@@ -369,3 +370,148 @@ def test_ragged_step_jaxpr_one_pallas_call_no_pool_scatter():
                 assert shape not in pool_shapes, (
                     f"pool-shaped scatter on the ragged path: {shape}")
     assert pallas_calls == 1, f"{pallas_calls} pallas_calls in step jaxpr"
+
+
+# ---------------------------------------------------------------------------
+# the step's own spans, step log and named scopes
+# ---------------------------------------------------------------------------
+
+_PHASES = ("admit", "repack", "ensure_pages", "assemble", "dispatch", "sync",
+           "record")
+_STEP_MODES = {
+    "ragged": dict(step_mode="ragged", prefill_chunk=8),
+    "ragged-spec": dict(step_mode="ragged", prefill_chunk=8,
+                        spec_decode=True, num_draft_tokens=2),
+    "split": dict(step_mode="split", prefill_chunk=8),
+    "split-spec": dict(step_mode="split", prefill_chunk=8,
+                       spec_decode=True, num_draft_tokens=2),
+    "split-monolithic": dict(step_mode="split", prefill_mode="monolithic"),
+}
+
+
+def _small_engine(**kw):
+    cfg = _cfg().replace(num_groups=2)
+    params, _ = model.init(jax.random.PRNGKey(0), cfg)
+    return ContinuousBatchingEngine(params, cfg, ServeConfig(
+        max_seq=48, max_slots=2, page_size=8, **kw))
+
+
+def _host_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("serve.")]
+    return out
+
+
+@pytest.mark.parametrize("mode", sorted(_STEP_MODES))
+def test_every_phase_span_nests_in_its_step_span(mode, tmp_path):
+    """Under the profiler, each step is a ``serve.step`` span numbered by
+    the engine's tick; every phase span lies inside one, and
+    ``serve.admit`` names the requests it admitted."""
+    eng = _small_engine(**_STEP_MODES[mode])
+    rng = np.random.default_rng(3)
+    ids = [eng.submit(p, m) for p, m in _churn_reqs(rng)]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert [e[3]["step_num"] for e in steps] == list(
+        range(1, len(steps) + 1))
+    assert len(steps) == eng._tick
+    phases = [e for e in events if e[0] != "serve.step"]
+    assert {e[0] for e in phases} >= {f"serve.{p}" for p in _PHASES} - (
+        {"serve.repack"} if mode.startswith("ragged") else set())
+    for name, lo, hi, _ in phases:
+        assert any(s[1] <= lo and hi <= s[2] for s in steps), name
+    admitted = " ".join(str(e[3]["requests"]) for e in phases
+                        if e[0] == "serve.admit" and "requests" in e[3])
+    assert set(map(int, admitted.split())) == set(ids)
+
+
+@pytest.mark.parametrize("mode", sorted(_STEP_MODES))
+def test_step_log_phases_add_up_to_the_step(mode):
+    eng = _small_engine(**_STEP_MODES[mode])
+    for p, m in _churn_reqs(np.random.default_rng(4)):
+        eng.submit(p, m)
+    eng.run()
+    log = list(eng.step_log)
+    assert [r.tick for r in log] == list(range(1, eng._tick + 1))
+    assert sum(r.dispatched for r in log) >= eng.steps
+    # the statements between phases take microseconds; a phase left
+    # unwrapped would show in every step, a pause of the host in one
+    unphased = [r.wall_s - sum(b - a for _, a, b in r.phases) for r in log]
+    assert min(unphased) >= 0 and np.median(unphased) < 1e-3, unphased
+    for r in log:
+        assert all(r.t0 <= a <= b <= r.t1 for _, a, b in r.phases)
+        assert {p for p, _, _ in r.phases} <= set(_PHASES)
+        assert r.host_s == pytest.approx(r.wall_s - r.seconds("sync"))
+    means = phase_means_ms(log)
+    done = [r for r in log if r.dispatched]
+    assert means["wall"] == pytest.approx(
+        1e3 * sum(r.wall_s for r in done) / len(done))
+    assert means["host"] == pytest.approx(means["wall"] - means["sync"])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """Compile afresh: the persistent cache's key leaves metadata out, so
+    an entry from before a named scope was added carries the names of
+    then (and a cache hit is no backend compile)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_step_log_counts_a_forced_recompile(no_persistent_cache):
+    """The step that compiles the ragged step counts its compile; a
+    fresh trace of the same step compiles again, in the step that first
+    runs it, and steps after it compile nothing."""
+    eng = _small_engine(prefill_chunk=8)
+    eng.submit(np.arange(5, dtype=np.int32), 6)
+    eng.step()
+    eng.step()
+    raw = eng._ragged_fn_raw
+    eng._ragged_fn = jax.jit(lambda *a: raw(*a))
+    eng.step()
+    eng.step()
+    compiles = [r.compiles for r in eng.step_log]
+    assert compiles[0] >= 1 and compiles[1] == 0, compiles
+    assert compiles[2] >= 1 and compiles[3] == 0, compiles
+
+
+def test_step_program_hlo_names_every_scope(no_persistent_cache):
+    """The compiled ragged step's HLO carries each named scope in its
+    instructions' op_name metadata, and the ragged kernel's name;
+    ``step_program`` hands back the executable the engine dispatched."""
+    import re
+
+    eng = _small_engine(prefill_chunk=8)
+    assert eng.step_program() is None
+    eng.submit(np.arange(5, dtype=np.int32), 3)
+    eng.run()
+    program = eng.step_program()
+    assert eng.step_program().as_text() == program.as_text()
+    text = program.as_text()
+    for scope in ("embed", "layer_loop", "qkv_proj", "rope", "ragged_attn",
+                  "o_proj", "ffn", "weight_quant", "lm_head", "sample",
+                  "mx_attn_ragged"):
+        assert re.search(rf'op_name="[^"]*/{scope}[/"]', text), scope
+    assert re.search(r'op_name="[^"]*/(qkv_proj|o_proj|ffn)/weight_quant/',
+                     text)

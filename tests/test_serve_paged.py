@@ -132,6 +132,36 @@ def test_continuous_matches_fixed_slot_greedy(quantize_kv):
     np.testing.assert_array_equal(got, want)
 
 
+def test_admission_latency_counts_from_the_given_arrival():
+    """``submit(arrival=...)`` moves a request's admission latency by how
+    much earlier than submit() it arrived: two requests served in the
+    same steps, one given an arrival 7.5 s back, read 7.5 s apart, and
+    each keeps its arrival, admit and first-token times in order."""
+    import time
+
+    cfg = _cfg(True)
+    params, _ = model.init(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, ServeConfig(
+        max_seq=24, max_slots=2, page_size=8))
+    prompt = np.arange(6, dtype=np.int32)
+    eng.submit(prompt, 2)
+    eng.run()  # compiles outside the requests under test
+    offset = 7.5
+    t = time.perf_counter()
+    eng.submit(prompt, 2, arrival=t - offset)
+    eng.submit(prompt + 1, 2)
+    early, now = list(eng.scheduler.queue)
+    eng.run()
+    assert early.arrival == t - offset and now.arrival >= t
+    for req in (early, now):
+        assert req.arrival <= req.admitted <= req.first_token
+    lat_early, lat_now = list(eng.admission_latencies)[-2:]
+    assert lat_early == pytest.approx(early.first_token - early.arrival)
+    assert lat_early - lat_now == pytest.approx(offset, abs=0.05)
+    stats = eng.cache_stats()
+    assert stats["admission_latency_p95"] == pytest.approx(lat_early)
+
+
 def test_ragged_churn_with_preemption_token_identical():
     """More requests than slots, ragged lengths, a pool tight enough to
     force swap preemption — every request must still match its own
