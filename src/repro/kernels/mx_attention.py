@@ -69,6 +69,7 @@ cannot name the format once more than one byte-backed format exists.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -376,20 +377,29 @@ def _quantize_rows(x, fmt_name: str, block_size: int):
 _FLASH_ROW_TILE_ELEMS = 4096
 
 
-def _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap):
+def _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap, row0=0):
     """One online-softmax accumulation step over a (PS, D) key/value tile.
 
     Shared by the decode/verify, prefill, and ragged kernels so the
     accumulation order (and therefore the f32 rounding) of every fused
     path is identical by construction. ``q`` (R, D) f32, ``mask``
-    (R, PS) bool. When R * D exceeds :data:`_FLASH_ROW_TILE_ELEMS` the
+    (R, PS) bool; the state of its rows is rows ``[row0, row0 + R)`` of
+    ``m_ref``, ``l_ref`` and ``acc_ref`` — the ragged kernel keeps every
+    KV head's rows in one scratch, and passes a traced ``row0`` that is a
+    multiple of R. When R * D exceeds :data:`_FLASH_ROW_TILE_ELEMS` the
     update runs over static row tiles (see there) — bit-identical to the
     untiled form because every row's state is independent.
     """
     rows, d = q.shape
     tile = max(1, _FLASH_ROW_TILE_ELEMS // max(d, 1))
     for lo in range(0, rows, tile):
-        sl = slice(lo, min(lo + tile, rows))
+        hi = min(lo + tile, rows)
+        sl = slice(lo, hi)  # rows of the query tile
+        # the same rows of the state; a traced start is a multiple of
+        # gcd(R, tile), which is what lets the TPU slice the scratch there
+        start = (row0 + lo if isinstance(row0, int)
+                 else pl.multiple_of(row0 + lo, math.gcd(rows, tile)))
+        st = pl.ds(start, hi - lo)
         s = jax.lax.dot_general(
             q[sl], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * (d ** -0.5)  # (r, PS)
@@ -397,18 +407,18 @@ def _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap):
             s = jnp.tanh(s / softcap) * softcap
         mrows = mask[sl]
         s = jnp.where(mrows, s, NEG_INF)
-        m_prev = m_ref[sl]  # (r, 1)
+        m_prev = m_ref[st]  # (r, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # the explicit mask (not just exp(NEG_INF - m)) guards the
         # all-masked tile: there m_new == NEG_INF and the difference is 0
         probs = jnp.where(mrows, jnp.exp(s - m_new), 0.0)  # (r, PS)
-        l_ref[sl] = l_ref[sl] * alpha + jnp.sum(probs, axis=-1,
+        l_ref[st] = l_ref[st] * alpha + jnp.sum(probs, axis=-1,
                                                 keepdims=True)
-        acc_ref[sl] = acc_ref[sl] * alpha + jax.lax.dot_general(
+        acc_ref[st] = acc_ref[st] * alpha + jax.lax.dot_general(
             probs, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[sl] = m_new
+        m_ref[st] = m_new
 
 
 def _cell(i):
@@ -1022,14 +1032,22 @@ def mx_attention_prefill_fused(q, k_chunk, v_chunk, ke_pool, ks_pool,
 def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
                            block_size: int, softcap, window, width: int,
                            group: int, mixed_fmts=None):
-    """One page tile of one (row, kv-head) ragged-step cell.
+    """One page of one ragged-step row, for every KV head of the row.
+
+    The grid is ``(R, P)``: each step loads the page's ``(KVH, PS, ·)``
+    block — one contiguous slab of the KV-head-major pool — and runs the
+    per-head work below once per KV head, head after head, so a row pays
+    one pipelined grid step per page rather than one per (head, page).
+    Each head's math, row tiling and page order are those of a
+    one-head-per-step walk, so outputs and written bytes do not depend
+    on how many heads share a step.
 
     The generalization that lets decode rows (1 new token), speculative
     verify windows (1 + K new tokens), and prefill chunks (up to W new
     tokens) coexist in ONE grid: each row carries only ``(row_start,
     seq_len)`` scalars — ``row_start`` is where this step's new tokens
     begin and ``seq_len = row_start + n_new`` where they end — and the
-    page walk splits into three regions per cell:
+    page walk splits into three regions per row:
 
       * ``first <= p < w0`` (resident pages, ``w0 = row_start // PS``):
         read the compact pool tile, dequantize in-register, fold into the
@@ -1050,7 +1068,7 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
       * ``p < first`` / ``p >= valid``: body predicated away, DMA elided
         by index-map clamping (the decode/verify kernels' skip rule).
 
-    Query rows: the cell holds ``W * G`` query rows; row r belongs to
+    Query rows: each head holds ``W * G`` query rows; row r belongs to
     query ``t = r // G`` at absolute position ``row_start + min(t,
     n_new - 1)`` — padding queries (t >= n_new: decode rows in a W > 1
     batch, the tail of a final partial chunk) clamp onto the last real
@@ -1063,11 +1081,12 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
     page — inactive rows then read and write only that page and no live
     page is ever touched by a dead row.
 
-    Mixed-format (tiered) pools: resident pages dequantize through the
-    per-page format id; write-window pages are guaranteed base-fp8 by
-    the engine (freshly written pages are hot), so old and new codes
-    merge in one format and the fp8 bytes bitcast into the full-width
-    uint8 rows exactly as in the prefill kernel.
+    Mixed-format (tiered) pools: one format id per pool page, shared by
+    its heads and read once per step; resident pages dequantize through
+    it; write-window pages are guaranteed base-fp8 by the engine
+    (freshly written pages are hot), so old and new codes merge in one
+    format and the fp8 bytes bitcast into the full-width uint8 rows
+    exactly as in the prefill kernel.
     """
     if mixed_fmts is None:
         (tbl_ref, start_ref, lens_ref, q_ref, kn_ref, vn_ref,
@@ -1081,16 +1100,22 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
          oke_ref, oks_ref, ove_ref, ovs_ref, visits_ref,
          m_ref, l_ref, acc_ref) = refs
     i = pl.program_id(0)
-    p = pl.program_id(2)
-    cell = _cell(i)
-    last = pl.num_programs(2) - 1
+    p = pl.program_id(1)
+    kvh = q_ref.shape[1]
+    rows = width * group
+    last = pl.num_programs(1) - 1
+
+    # the skip predicate's audit trail: the row's page-visit counter
+    # slots, one per kv-head, each counting every executed page body
+    slots = [i * kvh + h for h in range(kvh)]
 
     @pl.when(p == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        visits_ref[cell] = 0
+        for s in slots:
+            visits_ref[s] = 0
 
     start = start_ref[i]  # first new-token row of this step
     seq_len = lens_ref[i]  # resident rows incl. this step's new tokens
@@ -1099,42 +1124,60 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
     valid_pages = pl.cdiv(seq_len, page_size)
     first_page = _first_window_page(start, window, page_size)
 
-    def _attend_tile(k, v):
-        q = q_ref[0, 0].astype(jnp.float32)  # (W * G, D)
+    def _page_mask():
         kpos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        rows = width * group
         # row r belongs to query t = r // G at absolute position
         # start + min(t, n_new - 1): real queries get exactly the split
         # kernels' positions, padding queries clamp onto the last real one
         t = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
         qpos = start + jnp.minimum(t, n_new - 1)
-        mask = kpos <= qpos  # (R, PS)
+        mask = kpos <= qpos  # (W * G, PS)
         if window is not None:
             mask &= kpos > qpos - window
-        _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap)
+        return mask
+
+    def _attend_tile(h, k, v, mask):
+        q = q_ref[0, h].astype(jnp.float32)  # (W * G, D)
+        # head h's softmax state is rows [h * W * G, (h + 1) * W * G) of
+        # the 2-D scratch (a head axis in front of m's and l's one-lane
+        # minor dim does not tile)
+        _flash_update(m_ref, l_ref, acc_ref, q, k, v, mask, softcap,
+                      row0=h * rows)
+
+    def _dequant(elems, scales, fid):
+        if mixed_fmts is None:
+            return _dequant_rows(elems, scales, fmt_name, block_size)
+        return _dequant_rows_mixed(elems, scales, fid, mixed_fmts,
+                                   block_size)
+
+    def _each_head(body):
+        # a loop, not a static unroll: the same code for every head keeps
+        # the kernel's compile time and code size those of one head
+        def step(h, carry):
+            body(h)
+            return carry
+        jax.lax.fori_loop(0, kvh, step, 0)
 
     @pl.when((p >= first_page) & (p < w0))
     def _resident_page():
-        visits_ref[cell] += 1
-        if mixed_fmts is None:
-            k = _dequant_rows(ke_ref[0, 0], ks_ref[0, 0],
-                              fmt_name, block_size)  # (PS, D)
-            v = _dequant_rows(ve_ref[0, 0], vs_ref[0, 0],
-                              fmt_name, block_size)
-        else:
-            fid = fmts_ref[tbl_ref[i, p]]
-            k = _dequant_rows_mixed(ke_ref[0, 0], ks_ref[0, 0],
-                                    fid, mixed_fmts, block_size)
-            v = _dequant_rows_mixed(ve_ref[0, 0], vs_ref[0, 0],
-                                    fid, mixed_fmts, block_size)
-        _attend_tile(k, v)
+        for s in slots:
+            visits_ref[s] += 1
+        fid = None if mixed_fmts is None else fmts_ref[tbl_ref[i, p]]
+        mask = _page_mask()
+
+        def head(h):
+            k = _dequant(ke_ref[0, h], ks_ref[0, h], fid)  # (PS, D)
+            v = _dequant(ve_ref[0, h], vs_ref[0, h], fid)
+            _attend_tile(h, k, v, mask)
+        _each_head(head)
 
     @pl.when((p >= w0) & (p < valid_pages))
     def _write_page():
-        visits_ref[cell] += 1
-        kw = kn_ref[0, 0].astype(jnp.float32)  # (W, D) wide new rows
-        vw = vn_ref[0, 0].astype(jnp.float32)
+        for s in slots:
+            visits_ref[s] += 1
+        fid = None if mixed_fmts is None else fmts_ref[tbl_ref[i, p]]
+        mask = _page_mask()
         # scatter new row t onto page row j where start + t == p*PS + j:
         # a one-hot f32 matmul (products are 1.0*x or 0.0*x — exact), so
         # page rows outside [start, seq_len) gather exact zeros that the
@@ -1146,47 +1189,46 @@ def _mx_attn_ragged_kernel(*refs, page_size: int, fmt_name: str,
         kpos_rows = p * page_size + jrow[:, :1]  # (PS, 1)
         onehot = ((start + tcol) == (p * page_size + jrow)
                   ).astype(jnp.float32)  # (PS, W)
-        k_page = jax.lax.dot_general(
-            onehot, kw, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (PS, D)
-        v_page = jax.lax.dot_general(
-            onehot, vw, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        kq_e, kq_s = _quantize_rows(k_page, fmt_name, block_size)
-        vq_e, vq_s = _quantize_rows(v_page, fmt_name, block_size)
-        if mixed_fmts is not None:
-            kq_e = jax.lax.bitcast_convert_type(kq_e, jnp.uint8)
-            vq_e = jax.lax.bitcast_convert_type(vq_e, jnp.uint8)
-        # merge in the CODE domain: in-window page rows take this step's
-        # freshly quantized codes, the rest keep their stored bytes —
-        # then write the whole tile back through the aliased output
         in_w = (kpos_rows >= start) & (kpos_rows < seq_len)  # (PS, 1)
-        k_codes = jnp.where(in_w, kq_e, ke_ref[0, 0])
-        v_codes = jnp.where(in_w, vq_e, ve_ref[0, 0])
-        k_scales = jnp.where(in_w, kq_s, ks_ref[0, 0])
-        v_scales = jnp.where(in_w, vq_s, vs_ref[0, 0])
-        oke_ref[0, 0] = k_codes
-        ove_ref[0, 0] = v_codes
-        oks_ref[0, 0] = k_scales
-        ovs_ref[0, 0] = v_scales
-        # attend over the merged tile — identical bytes (and therefore
-        # identical f32 values) to what the split path's separate host
-        # install + page re-read would produce
-        if mixed_fmts is None:
-            _attend_tile(
-                _dequant_rows(k_codes, k_scales, fmt_name, block_size),
-                _dequant_rows(v_codes, v_scales, fmt_name, block_size))
-        else:
-            fid = fmts_ref[tbl_ref[i, p]]
-            _attend_tile(
-                _dequant_rows_mixed(k_codes, k_scales, fid, mixed_fmts,
-                                    block_size),
-                _dequant_rows_mixed(v_codes, v_scales, fid, mixed_fmts,
-                                    block_size))
+
+        def head(h):
+            kw = kn_ref[0, h].astype(jnp.float32)  # (W, D) wide new rows
+            vw = vn_ref[0, h].astype(jnp.float32)
+            k_page = jax.lax.dot_general(
+                onehot, kw, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (PS, D)
+            v_page = jax.lax.dot_general(
+                onehot, vw, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            kq_e, kq_s = _quantize_rows(k_page, fmt_name, block_size)
+            vq_e, vq_s = _quantize_rows(v_page, fmt_name, block_size)
+            if mixed_fmts is not None:
+                kq_e = jax.lax.bitcast_convert_type(kq_e, jnp.uint8)
+                vq_e = jax.lax.bitcast_convert_type(vq_e, jnp.uint8)
+            # merge in the CODE domain: in-window page rows take this
+            # step's freshly quantized codes, the rest keep their stored
+            # bytes — then write the whole tile back through the aliased
+            # output
+            k_codes = jnp.where(in_w, kq_e, ke_ref[0, h])
+            v_codes = jnp.where(in_w, vq_e, ve_ref[0, h])
+            k_scales = jnp.where(in_w, kq_s, ks_ref[0, h])
+            v_scales = jnp.where(in_w, vq_s, vs_ref[0, h])
+            oke_ref[0, h] = k_codes
+            ove_ref[0, h] = v_codes
+            oks_ref[0, h] = k_scales
+            ovs_ref[0, h] = v_scales
+            # attend over the merged tile — identical bytes (and
+            # therefore identical f32 values) to what the split path's
+            # separate host install + page re-read would produce
+            _attend_tile(h, _dequant(k_codes, k_scales, fid),
+                         _dequant(v_codes, v_scales, fid), mask)
+        _each_head(head)
 
     @pl.when(p == last)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        for h in range(kvh):
+            hrows = pl.ds(h * rows, rows)
+            o_ref[0, h] = (acc_ref[hrows] / l_ref[hrows]).astype(o_ref.dtype)
 
 
 def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
@@ -1201,8 +1243,12 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
     The single kernel behind ``ServeConfig.step_mode="ragged"``: every
     engine-step row — a plain decode token, a speculative verify window,
     or an in-flight prefill chunk — is one grid row of the SAME
-    ``(R, KVH, P)`` scalar-prefetch page walk, distinguished only by its
-    ``(row_start, seq_len)`` metadata. Each row's new K/V rows are
+    ``(R, P)`` scalar-prefetch page walk, distinguished only by its
+    ``(row_start, seq_len)`` metadata. A grid step loads one page for
+    all of the row's KV heads (the ``KVH`` the pools carry: the local
+    head slice under the serve engine's KV-head ``shard_map``) and
+    attends head after head, so a row pays one pipelined step per page
+    of its table, not one per (head, page). Each row's new K/V rows are
     quantized and merged into its pages *inside* the kernel through
     aliased pool outputs (see :func:`_mx_attn_ragged_kernel`), so a
     steady-state mixed batch costs exactly one device dispatch and the
@@ -1273,31 +1319,34 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
     lens = jnp.clip(jnp.asarray(seq_lens, jnp.int32), start + 1, start + w)
     qr = q.reshape(r, kvh, rows, d)
 
+    # pool blocks hold one page for all of the row's KV heads: the pool
+    # is KV-head major within a page, so (1, KVH, PS, width) is one
+    # contiguous slab
     def pool_in_spec(width_):
-        def imap(i, j, p, tbl, st, ln, *_fmts):
+        def imap(i, p, tbl, st, ln, *_fmts):
             # every page in [first, valid) is read — resident pages to
             # attend, write-window pages to merge with; skipped steps
             # clamp into that range so their DMA is elided
             valid = pl.cdiv(ln[i], ps)
             first = _first_window_page(st[i], window, ps)
-            return (tbl[i, jnp.clip(p, first, valid - 1)], j, 0, 0)
-        return pl.BlockSpec((1, 1, ps, width_), imap)
+            return (tbl[i, jnp.clip(p, first, valid - 1)], 0, 0, 0)
+        return pl.BlockSpec((1, kvh, ps, width_), imap)
 
-    def new_in_spec():
-        # the step's wide new rows: one (W, D) slab per (row, head),
-        # constant across the page walk (fetched once per cell)
-        return pl.BlockSpec((1, 1, w, d),
-                            lambda i, j, p, *_: (i, j, 0, 0))
+    def row_spec(rows_):
+        # per-row operands (queries, output, the step's wide new K/V
+        # rows): constant across the page walk, fetched once per row
+        return pl.BlockSpec((1, kvh, rows_, d),
+                            lambda i, p, *_: (i, 0, 0, 0))
 
     def pool_out_spec(width_):
-        def imap(i, j, p, tbl, st, ln, *_fmts):
+        def imap(i, p, tbl, st, ln, *_fmts):
             # steps below the write window park on its first page (it is
             # written before the index ever changes), steps past the
-            # last written page park on it (flushed once at cell end)
+            # last written page park on it (flushed once at row end)
             w0 = st[i] // ps
             valid = pl.cdiv(ln[i], ps)
-            return (tbl[i, jnp.clip(p, w0, valid - 1)], j, 0, 0)
-        return pl.BlockSpec((1, 1, ps, width_), imap)
+            return (tbl[i, jnp.clip(p, w0, valid - 1)], 0, 0, 0)
+        return pl.BlockSpec((1, kvh, ps, width_), imap)
 
     scalar_ops = [table, start, lens]
     if mixed:
@@ -1305,25 +1354,22 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
     ns = len(scalar_ops)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=ns,
-        grid=(r, kvh, pmax),
+        grid=(r, pmax),
         in_specs=[
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda i, j, p, *_: (i, j, 0, 0)),
-            new_in_spec(), new_in_spec(),
+            row_spec(rows), row_spec(w), row_spec(w),
             pool_in_spec(ed), pool_in_spec(nb),
             pool_in_spec(ed), pool_in_spec(nb),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda i, j, p, *_: (i, j, 0, 0)),
+            row_spec(rows),
             pool_out_spec(ed), pool_out_spec(nb),
             pool_out_spec(ed), pool_out_spec(nb),
             _VISITS_SPEC,
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),  # running max m
-            pltpu.VMEM((rows, 1), jnp.float32),  # running denominator l
-            pltpu.VMEM((rows, d), jnp.float32),  # rescaled partial output
+            pltpu.VMEM((kvh * rows, 1), jnp.float32),  # running max m
+            pltpu.VMEM((kvh * rows, 1), jnp.float32),  # running denominator
+            pltpu.VMEM((kvh * rows, d), jnp.float32),  # rescaled output
         ],
     )
     kernel = functools.partial(
@@ -1345,7 +1391,7 @@ def mx_attention_ragged_fused(q, k_new, v_new, ke_pool, ks_pool, ve_pool,
         # operands, then q, k_new, v_new, then the four pools)
         input_output_aliases={ns + 3 + k: 1 + k for k in range(4)},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mx_attn_ragged",  # the HLO instruction's and trace's name
     )(*scalar_ops, qr, k_new, v_new,

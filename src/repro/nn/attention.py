@@ -624,9 +624,10 @@ def apply_ragged(params, x, pool, page_rows, row_start, seq_lens,
     Inside the engine's KV-head-sharded serve step
     (``parallel.ctx.serve_tp_axis`` set, i.e. traced under the engine's
     ``shard_map``) the pool leaves and the wq/wk/wv projections carry
-    only this device's ``KVH / M`` head slice, so the kernel's grid —
-    already ``(R, KVH, P)`` — shards along its KV-head dimension for
-    free. The ONE collective of the whole step happens here: the kernel
+    only this device's ``KVH / M`` head slice, so the kernel — whose
+    ``(R, P)`` grid loads each page for all of the heads it is given —
+    walks the local heads only, with nothing else changed. The ONE
+    collective of the whole step happens here: the kernel
     output is all-gathered over the mesh axis (tiled along the KV-head
     dim, device order == head order) before the output projection, whose
     replicated ``wo`` then sees bit-identical full-width operands on

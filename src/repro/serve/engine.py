@@ -278,6 +278,20 @@ def _sub_jaxprs(params):
                     yield x
 
 
+def _walk_live(row_start, seq_lens, page_size: int, windows) -> float:
+    """Page bodies the ragged kernel executes over these rows, a layer:
+    each row's ``ceil(seq_len / page_size)`` pages less those wholly
+    below its sliding window (the kernel's ``_first_window_page``),
+    averaged over the attention layers' ``windows``."""
+    pages = -(-seq_lens // page_size)
+    total = 0
+    for w in windows:
+        first = 0 if w is None else np.maximum(
+            (row_start - w + 1) // page_size, 0)
+        total += int((pages - first).sum())
+    return total / len(windows)
+
+
 def _pallas_calls_in(jaxpr) -> int:
     """Device-kernel launches one execution of ``jaxpr`` performs.
 
@@ -739,6 +753,13 @@ class ContinuousBatchingEngine:
         # dispatches_per_mixed_step == 1 — is measured, never asserted
         self.dispatch_counts = {"decode": 0, "verify": 0, "prefill": 0,
                                 "ragged": 0, "write": 0, "repack": 0}
+        # the per-layer ragged kernel's page walk over the engine's life:
+        # page bodies executed and grid steps, a layer (steplog.StepRecord)
+        self.walk_live = 0.0
+        self.walk_grid = 0
+        self._walk_windows = tuple(
+            bd.window for _, _, bd in model.iter_layer_blocks(self.cfg_decode)
+            if bd.mixer == "attn")
         self._step_dispatches = 0
         self.mixed_steps = 0  # steps doing decode AND prefill work
         self.mixed_step_dispatches = 0
@@ -1598,6 +1619,14 @@ class ContinuousBatchingEngine:
                                                       extra_tokens=k)
             if not decode and not prefill:
                 return
+            if not self.megakernel:
+                live = _walk_live(row_start, seq_lens,
+                                  self.serve_cfg.page_size,
+                                  self._walk_windows)
+                grid = page_rows.shape[0] * page_rows.shape[1]
+                self._timer.walk_live, self._timer.walk_grid = live, grid
+                self.walk_live += live
+                self.walk_grid += grid
             if k:
                 for seq in decode:
                     history = np.concatenate(
@@ -1985,6 +2014,11 @@ class ContinuousBatchingEngine:
             "prefill_rows_per_step": (
                 self.prefill_tokens / self.prefill_dispatches
                 if self.prefill_dispatches else 0.0),
+            # share of the per-layer ragged kernel's grid steps that run
+            # a page body (the rest are skipped: past a row's last page
+            # or below its window)
+            "walk_live_share": (self.walk_live / self.walk_grid
+                                if self.walk_grid else 0.0),
         })
         if self.tiered:
             pool = sched.pool

@@ -19,6 +19,9 @@ host was doing while the device idled. The same boundaries, read with
 ``time.perf_counter``, go into a bounded in-memory log of the last
 ``STEP_LOG_LEN`` steps (``StepRecord``), with the backend compiles that
 began during each step: what an operator reads without a profiler.
+A step of the per-layer ragged kernel also records, from the rows the
+host assembled, how much of that kernel's page walk does work
+(``walk_live`` of ``walk_grid`` grid steps a layer).
 Phases run one after another and never nest, so a step's phase times
 add up to its wall time, less the few statements between them.
 """
@@ -58,7 +61,14 @@ def backend_compiles() -> int:
 class StepRecord:
     """One engine step: its tick, ``perf_counter`` seconds at its start
     and end and at the start and end of each phase, whether it
-    dispatched device work, and the backend compiles during it."""
+    dispatched device work, and the backend compiles during it.
+
+    ``walk_live`` and ``walk_grid`` describe the ragged page walk of a
+    step of the per-layer ragged kernel (0 on other steps): the page
+    bodies it executes, summed over its rows and averaged over the
+    attention layers (each row's ``ceil(seq_len / page_size)`` pages
+    less those below its sliding window), and its grid steps, rows x
+    page-table width, each a layer."""
 
     tick: int
     t0: float
@@ -66,6 +76,8 @@ class StepRecord:
     phases: Tuple[Tuple[str, float, float], ...]  # (phase, start, end)
     dispatched: bool
     compiles: int
+    walk_live: float = 0.0
+    walk_grid: int = 0
 
     @property
     def wall_s(self) -> float:
@@ -108,6 +120,7 @@ class StepTimer:
     def __init__(self, tick: int):
         self.tick = tick
         self.phases = []
+        self.walk_live, self.walk_grid = 0.0, 0  # set by a ragged step
         self.compiles0 = backend_compiles()
         self.span = jax.profiler.StepTraceAnnotation("serve.step",
                                                      step_num=tick)
@@ -121,7 +134,8 @@ class StepTimer:
         t1 = time.perf_counter()
         self.span.__exit__(None, None, None)
         return StepRecord(self.tick, self.t0, t1, tuple(self.phases),
-                          dispatched, backend_compiles() - self.compiles0)
+                          dispatched, backend_compiles() - self.compiles0,
+                          self.walk_live, self.walk_grid)
 
 
 def phase_means_ms(records) -> Optional[dict]:

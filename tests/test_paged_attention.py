@@ -22,6 +22,7 @@ from repro.core import quantize
 from repro.kernels import (gather_kv_pages, mx_attention_decode,
                            mx_attention_decode_fused,
                            mx_attention_decode_paged,
+                           mx_attention_ragged_fused,
                            mx_attention_verify_fused)
 
 RNG = np.random.default_rng(123)
@@ -283,6 +284,51 @@ def test_fused_visits_exactly_the_resident_pages():
     want = np.broadcast_to(np.ceil(lens / ps).astype(np.int32)[:, None],
                            (b, kvh))
     np.testing.assert_array_equal(np.asarray(visits)[:, :, 0], want)
+
+
+@pytest.mark.parametrize("window", [None, 10])
+@pytest.mark.parametrize("kvh", [1, 2, 8])
+def test_ragged_decode_rows_are_the_fused_decode_kernel(kvh, window):
+    """Decode rows through the ragged kernel, whose grid step walks one
+    page for all KV heads: each (row, head) visits exactly ceil(len/PS)
+    pages less the window's head pages, the output is bitwise the
+    one-head-a-step decode kernel's, and re-writing each row's newest
+    token (already in the pool) leaves every pool byte as it was."""
+    rng = np.random.default_rng(71 + kvh)
+    b, g, d, t, ps, w = 3, 2, 32, 64, 8, 4
+    wide_k = rng.normal(size=(b, kvh, t, d)).astype(np.float32)
+    wide_v = rng.normal(size=(b, kvh, t, d)).astype(np.float32)
+    kq = quantize(jnp.asarray(wide_k), "fp8_e4m3", 32)
+    vq = quantize(jnp.asarray(wide_v), "fp8_e4m3", 32)
+    pools, table = _paged_layout(kq, vq, b, kvh, t, ps, rng)
+    lens = np.array([64, 41, 7], np.int32)  # deep, mid, under the window
+    q = rng.normal(size=(b, kvh, w, g, d)).astype(np.float32)
+    k_new = rng.normal(size=(b, kvh, w, d)).astype(np.float32)  # padding
+    v_new = rng.normal(size=(b, kvh, w, d)).astype(np.float32)
+    for i in range(b):
+        k_new[i, :, 0] = wide_k[i, :, lens[i] - 1]
+        v_new[i, :, 0] = wide_v[i, :, lens[i] - 1]
+    names = ("ke", "ks", "ve", "vs")
+    got, new_pools, visits = mx_attention_ragged_fused(
+        jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
+        *(pools[k] for k in names), table, jnp.asarray(lens - 1),
+        jnp.asarray(lens), fmt_name="fp8_e4m3", block_size=32,
+        window=window, debug_visits=True)
+    first = (0 if window is None
+             else np.maximum((lens - 1 - window + 1) // ps, 0))
+    want_visits = np.ceil(lens / ps).astype(np.int32) - first
+    np.testing.assert_array_equal(
+        np.asarray(visits)[:, :, 0],
+        np.broadcast_to(want_visits[:, None], (b, kvh)))
+    want = mx_attention_decode_fused(
+        jnp.asarray(q[:, :, 0]), *(pools[k] for k in names), table,
+        jnp.asarray(lens), window=window)
+    np.testing.assert_array_equal(
+        np.asarray(got)[:, :, 0].view(np.uint32),
+        np.asarray(want).view(np.uint32))
+    for k, pool in zip(names, new_pools):
+        np.testing.assert_array_equal(np.asarray(pool).view(np.uint8),
+                                      np.asarray(pools[k]).view(np.uint8))
 
 
 def test_fused_never_materializes_gathered_cache():

@@ -17,14 +17,17 @@ to the split-dispatch oracle it replaces, at both layers:
 Plus the structural guarantee: one `pallas_call` per engine step layer
 and no pool-shaped scatter (`.at[].set` K/V write) on the ragged path.
 """
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import MXFP8, quantize
+from repro.core.formats import FORMAT_IDS
 from repro.kernels import (mx_attention_ragged_fused,
-                           mx_attention_verify_fused)
+                           mx_attention_verify_fused, mx_repack_pages)
 from repro.nn import BlockDef, ModelConfig, model
 from repro.serve import ContinuousBatchingEngine, ServeConfig
 from repro.serve.steplog import phase_means_ms
@@ -49,13 +52,18 @@ def _scatter_rows(pool, table_row, quant, lo, hi, ps):
         ks[pg, :, t % ps] = sc[:, t]
 
 
-def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
+def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101,
+                 window=None, mixed=False):
     """Three coexisting row modes against per-row verify oracles.
 
     Row 0: plain decode (n_new=1, mid-page start). Row 1: verify window
     (n_new=3, straddling a page boundary). Row 2: fresh prefill chunk
     (n_new=W from row 0). Row 3: continuation chunk with an unaligned,
     mid-page start — the case the aligned prefill kernel cannot run.
+
+    ``window`` runs a sliding-window layer; ``mixed`` turns the pools
+    into a tiered uint8 pool (``fmt`` must be fp8_e4m3) whose first
+    resident pages of rows 0 and 1 were repacked to fp6 and fp4.
     """
     rng = np.random.default_rng(seed)
     w = 8
@@ -105,6 +113,30 @@ def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
         _scatter_rows((have[0], have[1]), table[i], kq[i], 0, starts[i], ps)
         _scatter_rows((have[2], have[3]), table[i], vq[i], 0, starts[i], ps)
 
+    page_fmts = mixed_fmts = None
+    if mixed:
+        assert fmt == "fp8_e4m3"
+        mixed_fmts = ("fp8_e4m3", "fp6_e3m2", "fp4_e2m1")
+        page_fmts = np.full((npages,), FORMAT_IDS[fmt], np.int32)
+        want = [a.view(np.uint8) for a in want]
+        have = [a.view(np.uint8) for a in have]
+        # resident-only pages (wholly below the row's write window) age
+        # into narrower formats, identically in both pools
+        for row, dst in ((0, "fp6_e3m2"), (1, "fp4_e2m1")):
+            pg = int(table[row, 0])
+            assert starts[row] >= ps
+            for pools in (want, have):
+                pools[:] = [np.asarray(x) for x in mx_repack_pages(
+                    *(jnp.asarray(a) for a in pools),
+                    jnp.asarray([pg], jnp.int32),
+                    jnp.asarray([page_fmts[pg]], jnp.int32),
+                    jnp.asarray(1, jnp.int32), dst_fmt_name=dst,
+                    mixed_fmts=mixed_fmts, block_size=block_size)]
+            page_fmts[pg] = FORMAT_IDS[dst]
+        kw = dict(page_fmts=jnp.asarray(page_fmts), mixed_fmts=mixed_fmts)
+    else:
+        kw = {}
+
     q = rng.normal(size=(r, kvh, w, g, d)).astype(np.float32)
     k_new = rng.normal(size=(r, kvh, w, d)).astype(np.float32)  # padding
     v_new = rng.normal(size=(r, kvh, w, d)).astype(np.float32)
@@ -117,53 +149,132 @@ def _ragged_case(fmt, block_size, d=64, g=2, kvh=2, ps=8, seed=101):
         jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
         *(jnp.asarray(a) for a in have), jnp.asarray(table),
         jnp.asarray(starts, jnp.int32), jnp.asarray(totals, jnp.int32),
-        fmt_name=fmt, block_size=block_size, debug_visits=True)
-    return (np.asarray(out), [np.asarray(p) for p in pools],
-            np.asarray(visits), want, have, q, table, starts, n_news,
-            totals, ps)
+        fmt_name=fmt, block_size=block_size, window=window,
+        debug_visits=True, **kw)
+    return SimpleNamespace(
+        out=np.asarray(out), pools=[np.asarray(p) for p in pools],
+        visits=np.asarray(visits), want=want, have=have, q=q, k_new=k_new,
+        v_new=v_new, table=table, starts=starts, n_news=n_news,
+        totals=totals, ps=ps, fmt=fmt, block_size=block_size, window=window,
+        oracle_kw=kw)
 
 
-@pytest.mark.parametrize("fmt", ["fp8_e4m3", "fp8_e5m2", "fp4_e2m1"])
-@pytest.mark.parametrize("block_size", [16, 32, 64])
-def test_ragged_kernel_bit_matches_split_oracle(fmt, block_size):
-    (out, pools, visits, want, have, q, table, starts, n_news, totals,
-     ps) = _ragged_case(fmt, block_size)
+def _assert_matches_split_oracle(c):
+    """The ragged kernel's written bytes, outputs and page visits are
+    those of the host write + verify-kernel oracle.
 
+    A decode row at G = 1 is left out of the output comparison: there
+    the oracle's query tile is one row, and XLA:CPU rounds a one-row
+    product differently from the ragged kernel's W-row one (the parent
+    one-head-a-step kernel differs from it in the same bits);
+    :func:`_assert_each_head_alone` covers those rows."""
     # 1) in-kernel written pool bytes == host core.quantize writes, and
     #    rows the step does not own keep their exact old codes
-    for i in range(len(starts)):
-        for t in range(totals[i]):
-            pg, prow = table[i, t // ps], t % ps
-            for got, exp in zip(pools, want):
+    for i in range(len(c.starts)):
+        for t in range(c.totals[i]):
+            pg, prow = c.table[i, t // c.ps], t % c.ps
+            for got, exp in zip(c.pools, c.want):
                 np.testing.assert_array_equal(
                     got[pg, :, prow].view(np.uint8),
                     exp[pg, :, prow].view(np.uint8))
-    owned = {int(table[i, p]) for i in range(len(starts))
-             for p in range(starts[i] // ps, -(-totals[i] // ps))}
-    for pg in range(pools[0].shape[0]):
+    owned = {int(c.table[i, p]) for i in range(len(c.starts))
+             for p in range(c.starts[i] // c.ps, -(-c.totals[i] // c.ps))}
+    for pg in range(c.pools[0].shape[0]):
         if pg in owned:
             continue
-        for got, old in zip(pools, have):
+        for got, old in zip(c.pools, c.have):
             np.testing.assert_array_equal(got[pg].view(np.uint8),
                                           old[pg].view(np.uint8))
 
     # 2) attention output bit-matches the split verify kernel reading the
     #    host-written pool (same page walk, same flash accumulation)
-    for i in range(len(starts)):
-        n = n_news[i]
+    for i in range(len(c.starts)):
+        n = c.n_news[i]
+        if n * c.q.shape[3] == 1:
+            continue
         ref = np.asarray(mx_attention_verify_fused(
-            jnp.asarray(q[i:i + 1, :, :n]),
-            *(jnp.asarray(a) for a in want), jnp.asarray(table[i:i + 1]),
-            jnp.asarray([totals[i]], jnp.int32),
-            fmt_name=fmt, block_size=block_size))
+            jnp.asarray(c.q[i:i + 1, :, :n]),
+            *(jnp.asarray(a) for a in c.want),
+            jnp.asarray(c.table[i:i + 1]),
+            jnp.asarray([c.totals[i]], jnp.int32),
+            fmt_name=c.fmt, block_size=c.block_size, window=c.window,
+            **c.oracle_kw))
         np.testing.assert_array_equal(
-            out[i:i + 1, :, :n].view(np.uint32), ref.view(np.uint32))
+            c.out[i:i + 1, :, :n].view(np.uint32), ref.view(np.uint32))
 
-    # 3) exact page-visit audit: every page in [0, ceil(total/PS)) and
-    #    nothing else
-    expect = np.array([-(-t // ps) for t in totals], np.int32)
+    # 3) exact page-visit audit, per (row, kv-head): every page in
+    #    [first, ceil(total/PS)) and nothing else, where first skips the
+    #    pages wholly below the oldest query's window
+    totals, starts = np.array(c.totals), np.array(c.starts)
+    first = (0 if c.window is None
+             else np.maximum((starts - c.window + 1) // c.ps, 0))
+    expect = (-(-totals // c.ps) - first).astype(np.int32)
     np.testing.assert_array_equal(
-        visits[:, :, 0], np.broadcast_to(expect[:, None], visits.shape[:2]))
+        c.visits[:, :, 0],
+        np.broadcast_to(expect[:, None], c.visits.shape[:2]))
+
+
+def _assert_each_head_alone(c):
+    """Each KV head run alone through the kernel (a one-head walk, one
+    grid step per (row, head, page) as before heads shared a step) gives
+    the bits the all-heads call gave: outputs, written pools, visits."""
+    kw = dict(fmt_name=c.fmt, block_size=c.block_size, window=c.window,
+              debug_visits=True, **c.oracle_kw)
+    for h in range(c.q.shape[1]):
+        hs = slice(h, h + 1)
+        out, pools, visits = mx_attention_ragged_fused(
+            jnp.asarray(c.q[:, hs]), jnp.asarray(c.k_new[:, hs]),
+            jnp.asarray(c.v_new[:, hs]),
+            *(jnp.asarray(a[:, hs]) for a in c.have), jnp.asarray(c.table),
+            jnp.asarray(c.starts, jnp.int32),
+            jnp.asarray(c.totals, jnp.int32), **kw)
+        np.testing.assert_array_equal(np.asarray(out).view(np.uint32),
+                                      c.out[:, hs].view(np.uint32))
+        for got, all_heads in zip(pools, c.pools):
+            np.testing.assert_array_equal(
+                np.asarray(got).view(np.uint8),
+                all_heads[:, hs].view(np.uint8))
+        np.testing.assert_array_equal(np.asarray(visits), c.visits[:, hs])
+
+
+@pytest.mark.parametrize("fmt", ["fp8_e4m3", "fp8_e5m2", "fp4_e2m1"])
+@pytest.mark.parametrize("block_size", [16, 32, 64])
+def test_ragged_kernel_bit_matches_split_oracle(fmt, block_size):
+    _assert_matches_split_oracle(_ragged_case(fmt, block_size))
+
+
+@pytest.mark.parametrize("g", [1, 3, 4])
+@pytest.mark.parametrize("kvh", [1, 2, 8])
+def test_ragged_kernel_all_heads_a_step_bit_match_split_oracle(kvh, g):
+    """One grid step per (row, page) walks every KV head of the page in
+    turn: each head's output, written bytes and visits are still those
+    of the one-head-a-step verify oracle, and of the kernel run on that
+    head alone, for any head count and group."""
+    c = _ragged_case("fp8_e4m3", 32, d=32, g=g, kvh=kvh, seed=7 * kvh + g)
+    _assert_matches_split_oracle(c)
+    _assert_each_head_alone(c)
+
+
+@pytest.mark.parametrize("kvh", [1, 2, 8])
+def test_ragged_kernel_window_bit_matches_split_oracle(kvh):
+    """A sliding window of 2 skips the head page of rows 0, 1 and 3
+    (visits per head drop by one each) and masks the rest exactly."""
+    c = _ragged_case("fp8_e4m3", 32, d=32, g=2, kvh=kvh, window=2,
+                     seed=40 + kvh)
+    _assert_matches_split_oracle(c)
+    _assert_each_head_alone(c)
+    assert (c.visits[[0, 1, 3], :, 0]
+            < -(-np.array(c.totals)[[0, 1, 3], None] // c.ps)).all()
+
+
+@pytest.mark.parametrize("kvh", [2, 8])
+def test_ragged_kernel_mixed_format_pool_bit_matches_split_oracle(kvh):
+    """Tiered pool: resident pages in fp6 and fp4 beside fp8 ones; one
+    format id a page serves all of its heads."""
+    c = _ragged_case("fp8_e4m3", 32, d=32, g=3, kvh=kvh, mixed=True,
+                     seed=50 + kvh)
+    _assert_matches_split_oracle(c)
+    _assert_each_head_alone(c)
 
 
 def test_ragged_kernel_head_tiling_at_large_gdim():
@@ -171,17 +282,17 @@ def test_ragged_kernel_head_tiling_at_large_gdim():
     `_flash_update` path must stay bit-identical to the verify oracle
     (which shares the same tiling, so this also regression-checks both
     against the f32 einsum reference at kernel tolerance)."""
-    (out, pools, visits, want, have, q, table, starts, n_news, totals,
-     ps) = _ragged_case("fp8_e4m3", 32, d=128, g=8, kvh=2, seed=131)
-    for i in range(len(starts)):
-        n = n_news[i]
+    c = _ragged_case("fp8_e4m3", 32, d=128, g=8, kvh=2, seed=131)
+    for i in range(len(c.starts)):
+        n = c.n_news[i]
         ref = np.asarray(mx_attention_verify_fused(
-            jnp.asarray(q[i:i + 1, :, :n]),
-            *(jnp.asarray(a) for a in want), jnp.asarray(table[i:i + 1]),
-            jnp.asarray([totals[i]], jnp.int32),
+            jnp.asarray(c.q[i:i + 1, :, :n]),
+            *(jnp.asarray(a) for a in c.want),
+            jnp.asarray(c.table[i:i + 1]),
+            jnp.asarray([c.totals[i]], jnp.int32),
             fmt_name="fp8_e4m3", block_size=32))
         np.testing.assert_array_equal(
-            out[i:i + 1, :, :n].view(np.uint32), ref.view(np.uint32))
+            c.out[i:i + 1, :, :n].view(np.uint32), ref.view(np.uint32))
 
 
 def test_ragged_kernel_inactive_rows_only_touch_trash_page():
@@ -462,6 +573,70 @@ def test_step_log_phases_add_up_to_the_step(mode):
     assert means["wall"] == pytest.approx(
         1e3 * sum(r.wall_s for r in done) / len(done))
     assert means["host"] == pytest.approx(means["wall"] - means["sync"])
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_step_log_counts_the_ragged_page_walk(window):
+    """Each ragged step logs ``walk_live``, the page bodies its rows run
+    a layer (sum of ceil(seq_len / PS) less the window's head pages, as
+    the kernel's own visit counter reads them), and ``walk_grid``, rows
+    x page-table width; ``walk_live_share`` is their ratio over the
+    engine's life. A 20-token prompt streams its chunks while a short
+    one decodes, so steps hold prefill and decode rows together."""
+    ps = 8
+    cfg = _cfg().replace(num_groups=2,
+                         pattern=(BlockDef("attn", window=window),))
+    params, _ = model.init(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, ServeConfig(
+        max_seq=48, max_slots=2, page_size=ps, prefill_chunk=8))
+    assert eng.ragged
+    steps = []
+    assemble = eng.scheduler.assemble_ragged
+
+    def spy(*a, **kw):
+        out = assemble(*a, **kw)
+        _, start, lens, _, rows, modes, decode, prefill = out
+        if decode or prefill:
+            steps.append((start.copy(), lens.copy(), rows.copy(), modes))
+        return out
+
+    eng.scheduler.assemble_ragged = spy
+    rng = np.random.default_rng(5)
+    for n_prompt, n_out in [(4, 10), (20, 4), (6, 5)]:
+        eng.submit(rng.integers(0, 128, (n_prompt,)).astype(np.int32), n_out)
+    eng.run()
+    recs = [r for r in eng.step_log if r.walk_grid]
+    assert len(recs) == len(steps) > 0
+    assert all(r.walk_live == 0 for r in eng.step_log if not r.walk_grid)
+    mixed = [k for k, s in enumerate(steps) if {1, 2} <= set(s[3])]
+    assert mixed, "no step held both prefill and decode rows"
+    for rec, (start, lens, rows, _) in zip(recs, steps):
+        first = (0 if window is None
+                 else np.maximum((start - window + 1) // ps, 0))
+        assert rec.walk_live == (-(-lens // ps) - first).sum()
+        assert rec.walk_grid == rows.size
+    if window is not None:
+        assert any(r.walk_live < sum(-(-s[1] // ps))
+                   for r, s in zip(recs, steps))
+
+    # the kernel, given a mixed step's rows, visits what the log says
+    start, lens, rows, _ = steps[mixed[0]]
+    r, w = rows.shape[0], eng._ragged_width
+    kvh, g, d = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, 16
+    n_pages = eng.num_pages + 1  # + the trash page
+    elems = jnp.zeros((n_pages, kvh, ps, d), jnp.float8_e4m3fn)
+    scales = jnp.full((n_pages, kvh, ps, 1), 127, jnp.uint8)
+    _, _, visits = mx_attention_ragged_fused(
+        jnp.zeros((r, kvh, w, g, d)), jnp.zeros((r, kvh, w, d)),
+        jnp.zeros((r, kvh, w, d)), elems, scales, elems, scales,
+        jnp.asarray(rows), jnp.asarray(start), jnp.asarray(lens),
+        fmt_name="fp8_e4m3", block_size=16, window=window,
+        debug_visits=True)
+    assert int(np.asarray(visits)[:, 0, 0].sum()) == recs[mixed[0]].walk_live
+
+    share = sum(r.walk_live for r in recs) / sum(r.walk_grid for r in recs)
+    assert eng.cache_stats()["walk_live_share"] == pytest.approx(share)
+    assert 0 < share < 1
 
 
 @pytest.fixture

@@ -54,25 +54,42 @@ def _compile(fn, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("fmt", ["fp8_e4m3", "fp4_e2m1"])
-def test_ragged_kernel_compiles_at_phi4_widths(one_chip, fmt):
+def _compile_ragged(one_chip, fmt, r, kvh, g, w, ps, p):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     edt, ed = ((jnp.float8_e4m3fn, D) if fmt == "fp8_e4m3"
                else (jnp.uint8, D // 2))
-    elems, scales = s((NP, KVH, PS, ed), edt), s((NP, KVH, PS, D // 32),
-                                                  jnp.uint8)
+    n_pages = r * p + 1  # + the trash page
+    elems = s((n_pages, kvh, ps, ed), edt)
+    scales = s((n_pages, kvh, ps, D // 32), jnp.uint8)
 
     def step(q, kn, vn, ke, ks, ve, vs, tbl, st, ln):
         return mx_attention_ragged_fused(
             q, kn, vn, ke, ks, ve, vs, tbl, st, ln, fmt_name=fmt,
             block_size=32, interpret=False)
 
-    _compile(step, s((R, KVH, W, G, D), jnp.bfloat16),
-             s((R, KVH, W, D), jnp.bfloat16), s((R, KVH, W, D), jnp.bfloat16),
-             elems, scales, elems, scales, s((R, P), jnp.int32),
-             s((R,), jnp.int32), s((R,), jnp.int32))
+    _compile(step, s((r, kvh, w, g, D), jnp.bfloat16),
+             s((r, kvh, w, D), jnp.bfloat16), s((r, kvh, w, D), jnp.bfloat16),
+             elems, scales, elems, scales, s((r, p), jnp.int32),
+             s((r,), jnp.int32), s((r,), jnp.int32))
+
+
+@pytest.mark.parametrize("fmt", ["fp8_e4m3", "fp4_e2m1"])
+def test_ragged_kernel_compiles_at_phi4_widths(one_chip, fmt):
+    _compile_ragged(one_chip, fmt, R, KVH, G, W, PS, P)
+
+
+# the benchmark's decode cells: 32 rows, a 64-token window, pages of 16
+# rows over the whole max_seq (mistral-7b 4096, phi4-mini 4608); every
+# grid step holds one page of all 8 KV heads, and the scratch holds the
+# online-softmax state of all of them
+@pytest.mark.parametrize("widths", [
+    dict(r=32, kvh=8, g=4, w=64, ps=16, p=4096 // 16),   # mistral-7b
+    dict(r=32, kvh=8, g=3, w=64, ps=16, p=4608 // 16),   # phi4-mini-3.8b
+], ids=["mistral-7b", "phi4-mini-3.8b"])
+def test_ragged_kernel_compiles_at_decode_cell_widths(one_chip, widths):
+    _compile_ragged(one_chip, "fp8_e4m3", **widths)
 
 
 @pytest.mark.parametrize("dst", ["fp6_e3m2", "fp4_e2m1"])
