@@ -144,6 +144,40 @@ class Recorder:
             self.finished.append(req.id)
 
 
+def serve_kwargs(conf: dict, seed: int) -> dict:
+    """The ``ServeConfig`` fields that the configuration's ``serving``
+    sets: ``max_seq``, ``max_slots``, and where given ``mesh`` (a
+    ``[data, model]`` pair, as ``mesh_shape``)."""
+    srv = conf["serving"]
+    kw = dict(max_seq=srv["max_seq"], max_slots=srv["max_slots"],
+              seed=int(seed) & 0x7FFFFFFF)
+    if "mesh" in srv:
+        kw["mesh_shape"] = tuple(srv["mesh"])
+    return kw
+
+
+def mesh_size(serve_kw: dict) -> int:
+    return math.prod(serve_kw.get("mesh_shape", (1, 1)))
+
+
+def _check_chips(cell: dict, serve_kw: dict) -> None:
+    if mesh_size(serve_kw) != cell["chips"]:
+        raise SystemExit(
+            f"bench: the cell asks for {cell['chips']} chips but its "
+            f"configuration's mesh {serve_kw.get('mesh_shape', (1, 1))} "
+            f"spans {mesh_size(serve_kw)}")
+
+
+def _serve_mesh(serve_kw: dict, devices):
+    """The (data, model) mesh the sharded engine builds over ``devices``,
+    or None for one device."""
+    if mesh_size(serve_kw) == 1:
+        return None
+    from repro.launch.mesh import _make_mesh
+
+    return _make_mesh(serve_kw["mesh_shape"], ("data", "model"), devices)
+
+
 def _device_check(chips: int, require_tpu: bool):
     import jax
 
@@ -161,8 +195,8 @@ POOL_MARGIN_BYTES = 512 * 2**20  # HBM left free beside the pool and the step
 POOL_PAGES_MULTIPLE = 64         # the pool rounds down to this many pages, so
                                  # that small changes in free HBM keep one shape
 PROBE_PAGES = 320                # the smaller pool the step is compiled at to
-                                 # size the pool (twice that is the other); it
-                                 # holds one sequence of max_seq 4608 or less
+                                 # size the pool (twice that is the other),
+                                 # unless one sequence of max_seq needs more
 
 
 class _Captured(Exception):
@@ -188,17 +222,31 @@ def _step_args(engine) -> tuple:
     return got[0]
 
 
+def probe_pages(max_seq: int, page_size: int) -> int:
+    """The smaller probe pool: PROBE_PAGES, or one whole sequence."""
+    return max(PROBE_PAGES, -(-max_seq // page_size))
+
+
 def step_memory(step_fn, args, cfg, slots: int, pages: int,
-                page_size: int) -> dict:
+                page_size: int, mesh=None, pool_specs=None) -> dict:
     """The compiler's memory analysis of the ragged step with a pool of
     ``pages`` pages (trash page included): argument bytes, and the bytes
-    the step needs beyond its arguments while it runs."""
+    the step needs beyond its arguments while it runs, on each device
+    (the pool laid out by ``pool_specs`` on ``mesh``, where given)."""
     import jax
 
     from repro.nn import model
 
     cache = jax.eval_shape(
         lambda: model.init_paged_cache(cfg, slots, pages, page_size))
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+
+        flat, treedef = jax.tree_util.tree_flatten(cache)
+        cache = treedef.unflatten([
+            jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for x, spec in zip(flat, treedef.flatten_up_to(pool_specs))])
     ma = step_fn.lower(args[0], cache, *args[2:]).compile().memory_analysis()
     return {"pages": pages, "argument": ma.argument_size_in_bytes,
             "extra": (ma.temp_size_in_bytes + ma.output_size_in_bytes
@@ -206,9 +254,10 @@ def step_memory(step_fn, args, cfg, slots: int, pages: int,
 
 
 def fit_pool(probes: list, free: int, trash: int) -> int:
-    """Pages that ``free`` bytes hold beside the step, less the margin:
-    the pool's bytes and the step's extra bytes are linear in the pages,
-    read from two compiles of the step (``step_memory``)."""
+    """Pages that ``free`` bytes (the least over the devices) hold beside
+    the step, less the margin: the pool's bytes and the step's extra
+    bytes on a device are linear in the pages, read from two compiles of
+    the step (``step_memory``)."""
     lo, hi = probes
     span = hi["pages"] - lo["pages"]
     per_page = (hi["argument"] - lo["argument"]) / span
@@ -226,30 +275,65 @@ def fit_pool(probes: list, free: int, trash: int) -> int:
     return pages
 
 
-def _pool_pages(conf: dict, cfg, params, serve_kw: dict, device) -> int:
-    """The pool a deployment would give this chip: the pages that the HBM
-    left free after the weights holds beside the compiled step's own
-    needs, with a margin. The step is compiled at two probe pool sizes
-    from an engine with a small pool, and its memory analysis read."""
+def _built(engine, serve_kw: dict):
+    """``engine``, unless it fell back from the sharded ragged step."""
+    if not engine.ragged:
+        raise RuntimeError("the engine fell back from the ragged step")
+    if mesh_size(serve_kw) > 1 and engine.mesh is None:
+        raise RuntimeError(f"the engine fell back to one device from the "
+                           f"mesh {serve_kw['mesh_shape']}")
+    return engine
+
+
+def _pool_pages(conf: dict, cfg, params, serve_kw: dict, devices) -> int:
+    """The pool a deployment would give these chips: the pages that the
+    HBM left free after the weights holds beside the compiled step's own
+    needs, on the fullest chip, with a margin. The step is compiled at
+    two probe pool sizes from an engine with a small pool, and its memory
+    analysis read."""
     srv = conf["serving"]
     if "num_pages" in srv:
         return srv["num_pages"]
     from repro.launch.serve import build_engine
     from repro.serve import ServeConfig
 
-    probe = build_engine(cfg, ServeConfig(num_pages=PROBE_PAGES, **serve_kw),
-                         params, "continuous")
+    page_size = ServeConfig.page_size
+    small = probe_pages(serve_kw["max_seq"], page_size)
+    probe = _built(build_engine(cfg, ServeConfig(num_pages=small, **serve_kw),
+                                params, "continuous"), serve_kw)
     step_fn, trash = probe._ragged_fn, probe._trash_pages
+    layout = ({} if probe.mesh is None else
+              {"mesh": probe.mesh, "pool_specs": probe._pool_specs})
     args = _step_args(probe)
     del probe
     probes = [step_memory(step_fn, args, cfg, serve_kw["max_slots"], n,
-                          ServeConfig.page_size)
-              for n in (PROBE_PAGES, 2 * PROBE_PAGES)]
-    del args, step_fn
+                          page_size, **layout)
+              for n in (small, 2 * small)]
+    del args, step_fn, layout
     gc.collect()
-    stats = device.memory_stats()
-    return fit_pool(probes, stats["bytes_limit"] - stats["bytes_in_use"],
-                    trash)
+    free = [s["bytes_limit"] - s["bytes_in_use"]
+            for s in (d.memory_stats() for d in devices)]
+    return fit_pool(probes, min(free), trash)
+
+
+def build_cell_engine(conf: dict, seed: int, devices):
+    """The serving engine of a cell's configuration over ``devices``:
+    the seed's weights (drawn in the mesh's layout where the
+    configuration gives one), the pool that ``_pool_pages`` sizes, and
+    the engine built on them, refused if it fell back."""
+    from repro.launch.serve import build_engine
+    from repro.serve import ServeConfig
+
+    program, _ = family(conf)
+    serve_kw = serve_kwargs(conf, seed)
+    cfg = program.program_config(conf)
+    mesh = _serve_mesh(serve_kw, devices)
+    params = program.program_params(conf, seed, mesh)
+    program.check_layout(conf, cfg, params)
+    num_pages = _pool_pages(conf, cfg, params, serve_kw, devices)
+    return _built(build_engine(cfg, ServeConfig(num_pages=num_pages,
+                                                 **serve_kw),
+                               params, "continuous"), serve_kw)
 
 
 _COMPILES = []
@@ -300,12 +384,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     t_start = time.perf_counter() if t_start is None else t_start
     spec = spec or cell_spec(name)
     cell, conf, mix = spec["cell"], spec["conf"], spec["mix"]
+    serve_kw = serve_kwargs(conf, seed)
+    _check_chips(cell, serve_kw)
     devices = _device_check(cell["chips"], require_tpu)
     import jax
 
     from repro.launch.compile_cache import setup_compile_cache
-    from repro.launch.serve import build_engine
-    from repro.serve import ServeConfig
 
     cache_dir = setup_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -314,26 +398,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         f"count {len(devices)}; compile cache {cache_dir}")
     peak = work.peaks(dev.device_kind) if require_tpu else None
     compiles = _compile_counter()
-    program, _ = family(conf)
 
     # -- set-up ---------------------------------------------------------
-    cfg = program.program_config(conf)
-    params = program.program_params(conf, seed)
-    program.check_layout(conf, cfg, params)
-    srv = conf["serving"]
-    page_size = ServeConfig.page_size
-    serve_kw = dict(max_seq=srv["max_seq"], max_slots=srv["max_slots"],
-                    seed=int(seed) & 0x7FFFFFFF)
-    num_pages = _pool_pages(conf, cfg, params, serve_kw, dev)
-    engine = build_engine(cfg, ServeConfig(num_pages=num_pages, **serve_kw),
-                          params, "continuous")
-    del params
-    if not engine.ragged:
-        raise RuntimeError("the engine fell back from the ragged step")
+    engine = build_cell_engine(conf, seed, devices)
+    num_pages, page_size = engine.num_pages, engine.serve_cfg.page_size
     stats = engine.cache_stats()
     log(f"pool: {num_pages} pages of {page_size} tokens, "
         f"{stats['page_bytes']} bytes a page, "
         f"{stats['allocated_bytes']} bytes allocated")
+    if engine.mesh is not None:
+        log(f"mesh: {dict(engine.mesh.shape)} over "
+            f"{[d.id for d in engine.mesh.devices.flat]}, KV-head shards "
+            f"{engine.tp}, step width {engine.serve_cfg.prefill_chunk}")
     rec = Recorder(engine)
     if patch is not None:
         patch(engine)
@@ -427,7 +503,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
            and engine.scheduler.has_work
            and time.perf_counter() - t_wait < TTFT_WAIT_S):
         _step(engine, rec, [], False)
-    mem_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devices)
 
     # -- end-to-end metrics ---------------------------------------------
     ttft = [(rec.times[rid][0] - due[rid]) * 1e3 for rid in due_in
@@ -458,7 +535,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         log(f"generator: submitted {len(late)} requests, late by median "
             f"{percentile(late, 50) * 1e3:.3f} ms, max "
             f"{max(late) * 1e3:.3f} ms (one engine step at most)")
-    log(f"memory: peak {mem_peak} bytes in use on {dev.device_kind}")
+    log(f"memory: peak {mem_peak} bytes in use on the fullest of "
+        f"{len(devices)} {dev.device_kind}")
 
     # -- per-layer metrics ----------------------------------------------
     metrics = {}

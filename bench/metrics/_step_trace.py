@@ -1,7 +1,7 @@
 """Shared by the step readers: the traced steps' device time and work."""
 import work
 
-STEP_MODULE = r"ragged_step"
+STEP_MODULE = r"ragged_step|sharded_step"
 
 
 def traced_step_work(ctx):

@@ -2,6 +2,9 @@
 model config and the weights, in the program's own layout."""
 from __future__ import annotations
 
+import functools
+import operator
+
 import weights
 
 # program parameter path -> weight name (``weights.dense_gqa_specs``)
@@ -60,9 +63,9 @@ def program_config(conf: dict):
     return cfg
 
 
-def program_params(conf: dict, seed: int):
-    """The seed's weights (``weights.make``) in the program's tree."""
-    flat = weights.make(weights.dense_gqa_specs(conf), seed)
+def _tree(flat: dict) -> dict:
+    """name -> leaf, moved into the program's parameter tree."""
+    flat = dict(flat)
     tree = {}
     for path, name in _LEAVES.items():
         if name not in flat:
@@ -74,6 +77,28 @@ def program_params(conf: dict, seed: int):
     if flat:
         raise ValueError(f"weights with no place in the program: {flat}")
     return tree
+
+
+def program_params(conf: dict, seed: int, mesh=None):
+    """The seed's weights (``weights.make``) in the program's tree; on a
+    ``mesh``, drawn straight into the sharded serving engine's layout
+    (``serve_param_specs``), so that placing them copies nothing."""
+    specs = weights.dense_gqa_specs(conf)
+    shardings = None
+    if mesh is not None:
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+
+        from repro.parallel.sharding import serve_param_specs
+
+        pspecs = serve_param_specs(_tree({
+            n: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+            for n, (shape, _) in specs.items()}))
+        shardings = {name: NamedSharding(mesh, functools.reduce(
+            operator.getitem, path, pspecs))
+            for path, name in _LEAVES.items() if name in specs}
+    return _tree(weights.make(specs, seed, shardings=shardings))
 
 
 def check_layout(conf: dict, cfg, tree) -> None:

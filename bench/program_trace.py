@@ -16,7 +16,6 @@ its root instruction.
 """
 from __future__ import annotations
 
-import bisect
 import collections
 import heapq
 import json
@@ -93,18 +92,15 @@ def exclusive_seconds(events) -> list:
 def scope_seconds(red, scopes: dict, module: str) -> collections.Counter:
     """{scope: device seconds per chip} of the ops inside the programs
     whose ``XLA Modules`` events match ``module``, each op's own time
-    (``exclusive_seconds``) counted to its instruction's scope."""
-    spans = sorted((e.start, e.end) for e in red.module_events(module))
-    starts = [s for s, _ in spans]
-    inside = []
-    for e in red.ops:
-        i = bisect.bisect_right(starts, e.start) - 1
-        if i >= 0 and e.end <= spans[i][1]:
-            inside.append(e)
+    (``exclusive_seconds``, on its own chip) counted to its instruction's
+    scope."""
+    rx = re.compile(module)
     tally = collections.Counter()
-    for e, seconds in exclusive_seconds(inside):
-        tally[scopes.get(instruction(e.name), UNSCOPED)] += (seconds
-                                                            / red.chips)
+    for ops, modules in red.per_chip or [(red.ops, red.modules)]:
+        steps = [e for e in modules if rx.search(e.name)]
+        for e, seconds in exclusive_seconds(bench_trace.inside(ops, steps)):
+            tally[scopes.get(instruction(e.name), UNSCOPED)] += (seconds
+                                                                / red.chips)
     return tally
 
 
@@ -126,7 +122,8 @@ def idle_by_span(data, red=None, n: int = 12) -> list:
     red = red or bench_trace.reduce(data)
     return bench_trace.idle_gaps(
         bench_trace.Reduction(red.window, red.chips, red.ops, red.modules,
-                              host_spans(data), red.busy_s), n)
+                              host_spans(data), red.busy_s, red.per_chip),
+        n)
 
 
 def idle_intervals(red) -> list:

@@ -10,6 +10,7 @@ the window, averaged over the chips.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import re
@@ -43,6 +44,7 @@ class Reduction:
     modules: list           # Events of every chip's modules line
     host: list              # host Events named bench.*
     busy_s: float           # mean over chips of the union of op time
+    per_chip: list = None   # (ops, modules) of each chip, as above
 
     @property
     def window_s(self) -> float:
@@ -110,18 +112,20 @@ def reduce(data, window_span: str = "bench.window") -> Reduction:
         raise ValueError("no TPU device plane in the trace")
     if window is None:
         raise ValueError(f"no host span {window_span!r} in the trace")
-    ops, modules, busy = [], [], []
+    ops, modules, busy, per_chip = [], [], [], []
     for plane in devices:
         lines = {line.name: line for line in plane.lines}
         if OPS not in lines:
             raise ValueError(f"{plane.name} has no {OPS!r} line")
         dev_ops = _events(lines[OPS], *window)
+        dev_modules = (_events(lines[MODULES], *window) if MODULES in lines
+                       else [])
         ops += dev_ops
+        modules += dev_modules
         busy.append(_union(dev_ops))
-        if MODULES in lines:
-            modules += _events(lines[MODULES], *window)
+        per_chip.append((dev_ops, dev_modules))
     return Reduction(window, len(devices), ops, modules, host,
-                     sum(busy) / len(busy))
+                     sum(busy) / len(busy), per_chip)
 
 
 def self_seconds(events) -> list:
@@ -139,12 +143,68 @@ def self_seconds(events) -> list:
     return [(e, own[i]) for i, e in enumerate(events)]
 
 
+# collectives: an op event reads "%<instruction> = <type> <opcode>(...";
+# the instruction's name (all_gather.3, all-gather-start.1) or its opcode
+# (all-gather, or all-gather-start and -done of an async pair) names one
+_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
+                "collective-permute", "all-to-all")
+COLLECTIVE_NAME = re.compile(
+    "^%?(?:" + "|".join(k.replace("-", "[-_]") for k in _COLLECTIVES) + ")")
+COLLECTIVE_OPCODE = re.compile(
+    " (?:" + "|".join(_COLLECTIVES) + r")(?:-start|-done)?\(")
+
+
+def is_collective(e: Event) -> bool:
+    name, _, text = e.name.partition(" = ")
+    return bool(COLLECTIVE_NAME.match(name)
+                or COLLECTIVE_OPCODE.search(text))
+
+
+def inside(ops, spans) -> list:
+    """The ops that lie within one of the (module) events ``spans``."""
+    spans = sorted((s.start, s.end) for s in spans)
+    starts = [s for s, _ in spans]
+    out = []
+    for e in ops:
+        i = bisect.bisect_right(starts, e.start) - 1
+        if i >= 0 and e.end <= spans[i][1]:
+            out.append(e)
+    return out
+
+
+def collective_seconds(ops) -> tuple:
+    """(collective seconds, exposed seconds) of one chip's ops: the union
+    of its collectives' time, and the part of it that no other op
+    overlaps. An op that spans a whole collective holds it (a loop
+    around it) and is no overlap; one that covers only part of it, or
+    lies inside it, runs beside it."""
+    bounds = sorted({t for e in ops for t in (e.start, e.end)})
+    order = sorted(ops, key=lambda e: e.start)
+    total = exposed = 0.0
+    live, j = [], 0
+    for a, b in zip(bounds, bounds[1:]):
+        while j < len(order) and order[j].start <= a:
+            live.append(order[j])
+            j += 1
+        live = [e for e in live if e.end > a]
+        coll = [e for e in live if is_collective(e)]
+        if not coll:
+            continue
+        total += b - a
+        if not any(not is_collective(o) and not any(
+                o.start <= c.start and c.end <= o.end for c in coll)
+                for o in live):
+            exposed += b - a
+    return total, exposed
+
+
 def top_ops(red: Reduction, label, n: int = 10) -> list:
     """[label, seconds per chip] of the ops that took most device time of
-    their own."""
+    their own (nested ops taken out on their own chip)."""
     tally = collections.Counter()
-    for e, seconds in self_seconds(red.ops):
-        tally[label(e)] += seconds / red.chips
+    for ops, _ in red.per_chip or [(red.ops, None)]:
+        for e, seconds in self_seconds(ops):
+            tally[label(e)] += seconds / red.chips
     return [[k, v] for k, v in tally.most_common(n)]
 
 

@@ -46,9 +46,12 @@ def dense_gqa_specs(conf: dict) -> dict:
     return specs
 
 
-def make(specs: dict, seed: int, dtype=jnp.bfloat16) -> dict:
+def make(specs: dict, seed: int, dtype=jnp.bfloat16,
+         shardings: dict = None) -> dict:
     """Draw every leaf of ``specs`` in ``dtype`` in one jitted call; each
-    leaf is drawn and cast inside one fusion, so no f32 copy exists."""
+    leaf is drawn and cast inside one fusion, so no f32 copy exists.
+    ``shardings`` (name -> sharding) places each leaf as it is drawn,
+    with the same values (JAX's partitionable threefry)."""
     def draw(key):
         out = {}
         for name, (shape, std) in specs.items():
@@ -57,4 +60,6 @@ def make(specs: dict, seed: int, dtype=jnp.bfloat16) -> dict:
                          * std).astype(dtype)
         return out
 
-    return jax.block_until_ready(jax.jit(draw)(seed_key(seed)))
+    jitted = (jax.jit(draw) if shardings is None
+              else jax.jit(draw, out_shardings=shardings))
+    return jax.block_until_ready(jitted(seed_key(seed)))

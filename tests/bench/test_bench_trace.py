@@ -73,6 +73,14 @@ def test_reduction_by_hand():
                                   "outside bench spans": 400e-6})
 
 
+def test_reduction_keeps_each_chips_events():
+    red = bench_trace.reduce(synthetic())
+    assert [len(ops) for ops, _ in red.per_chip] == [3, 1]
+    assert [[m.name for m in mods] for _, mods in red.per_chip] == [
+        ["jit__ragged_step_fn(1)"], []]
+    assert sum(len(ops) for ops, _ in red.per_chip) == len(red.ops)
+
+
 def test_trace_readers_by_hand():
     from metrics import device_idle_share, ragged_attn_roofline, step_mfu
 
